@@ -4,51 +4,54 @@
 when PSRuntime(ps_kernels=True).  With pallas off it is exactly the seed
 `np.add.at` path; with pallas on/interpret it routes through the kernel,
 which accumulates duplicate rows in the same submission order, so the final
-state stays bitwise equal to the simulator either way.  Shard state is f64;
-the jax path runs under enable_x64 so no precision is lost in transit.
+state stays bitwise equal to the simulator either way.  An f64 block runs
+under `jax.enable_x64` so no precision is lost in transit (interpret mode
+only: the TPU kernel refuses f64 with `kernel.DeviceDtypeError`).
 """
 from __future__ import annotations
+
+import contextlib
+import functools
 
 import numpy as np
 
 from repro.kernels import pallas_mode
 
 
-def _jax_scatter_add(dense: np.ndarray, rows: np.ndarray,
-                     delta: np.ndarray, mode: str) -> np.ndarray:
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
+@functools.lru_cache(maxsize=1)
+def _jitted_kernel():
+    import jax
 
-    with enable_x64():
-        if mode in ("on", "interpret"):
-            from repro.kernels.ps_apply import kernel
-            out = kernel.scatter_add_pallas(
-                jnp.asarray(dense), jnp.asarray(rows, jnp.int32),
-                jnp.asarray(delta), interpret=(mode == "interpret"))
-        else:
-            from repro.kernels.ps_apply import ref
-            out = ref.scatter_add(jnp.asarray(dense),
-                                  jnp.asarray(rows, jnp.int32),
-                                  jnp.asarray(delta))
-        return np.asarray(out)
+    from repro.kernels.ps_apply import kernel
+    return jax.jit(kernel.scatter_add_pallas, static_argnames=("interpret",),
+                   donate_argnums=(0,))
 
 
 def scatter_add_inplace(dense: np.ndarray, rows: np.ndarray,
-                        delta: np.ndarray) -> None:
-    """Accumulate delta[i] into dense[rows[i]] in place (np.add.at order)."""
+                        delta: np.ndarray) -> bool:
+    """Accumulate delta[i] into dense[rows[i]] in place (np.add.at order).
+
+    Returns True when the batch went through the Pallas kernel."""
     mode = pallas_mode()
     if mode == "off" or rows.shape[0] == 0:
         np.add.at(dense, rows, delta)
-        return
+        return False
+    import jax
+    import jax.numpy as jnp
+
     n, r = rows.shape[0], dense.shape[0]
-    # Pad N up to a power of two with no-op rows targeting the kernel's
-    # dummy row R, so jit retraces are bounded to O(log max-batch) shapes.
+    # Pad N up to a power of two with entries the kernel skips (row R), so
+    # jit retraces are bounded to O(log max-batch) shapes.
     npad = max(8, 1 << (n - 1).bit_length())
-    if npad != n:
-        rows_p = np.full(npad, r, np.int32)
-        rows_p[:n] = rows
-        delta_p = np.zeros((npad, dense.shape[1]), dense.dtype)
-        delta_p[:n] = delta
-    else:
-        rows_p, delta_p = rows.astype(np.int32), delta
-    dense[...] = _jax_scatter_add(dense, rows_p, delta_p, mode)
+    rows_p = np.full(npad, r, np.int32)
+    rows_p[:n] = rows
+    delta_p = np.zeros((npad, dense.shape[1]), dense.dtype)
+    delta_p[:n] = delta
+    x64 = (jax.enable_x64(True) if dense.dtype == np.float64
+           else contextlib.nullcontext())
+    with x64:
+        out = _jitted_kernel()(jnp.asarray(dense), jnp.asarray(rows_p),
+                               jnp.asarray(delta_p),
+                               interpret=(mode == "interpret"))
+        dense[...] = np.asarray(out)
+    return True

@@ -33,19 +33,13 @@ def _kernel(a_ref, b_ref, init_ref, out_ref, h_ref):
         h_ref[...] = init_ref[...].astype(jnp.float32)
 
     def step(t, h):
-        # jax 0.4.37's interpret-mode discharge rules choke on bare int
-        # indices mixed with dynamic slices — keep every axis a (d)slice
-        a_t = pl.load(a_ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                              pl.dslice(None)))[0, 0].astype(jnp.float32)
-        b_t = pl.load(b_ref, (pl.dslice(0, 1), pl.dslice(t, 1),
-                              pl.dslice(None)))[0, 0].astype(jnp.float32)
+        a_t = a_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        b_t = b_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
         h = a_t * h + b_t
-        pl.store(out_ref, (pl.dslice(0, 1), pl.dslice(t, 1), pl.dslice(None)),
-                 h[None, None].astype(out_ref.dtype))
+        out_ref[0, pl.ds(t, 1), :] = h.astype(out_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, a_ref.shape[1], step, h_ref[...][0])
-    h_ref[...] = h[None]
+    h_ref[...] = jax.lax.fori_loop(0, a_ref.shape[1], step, h_ref[...])
 
 
 def linear_recurrence_pallas(a: jnp.ndarray, b: jnp.ndarray,

@@ -3,39 +3,29 @@
 Defined as FUNCTIONS so importing this module never touches jax device
 state.  The dry-run sets XLA_FLAGS --xla_force_host_platform_device_count
 *before* any jax import (see dryrun.py) to obtain 256/512 host devices.
+
+Every mesh axis is ``Auto``: the step functions place data with explicit
+``shard_map`` specs and collectives, which is what Auto axes mean.  (Since
+jax 0.7 ``jax.make_mesh`` defaults to ``Explicit`` axes, under which the
+same programs fail with sharding-type errors.)
 """
 from __future__ import annotations
 
 from typing import Tuple
 
 import jax
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    jax >= 0.6 exposes ``jax.shard_map(..., check_vma=...)``; older releases
-    only have ``jax.experimental.shard_map.shard_map(..., check_rep=...)``.
-    Replication checking is disabled in both spellings (the step functions
-    use explicit collectives).
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 chips per pod; 2 pods when multi_pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes_of(mesh) -> Tuple[str, ...]:

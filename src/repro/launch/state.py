@@ -56,8 +56,16 @@ def add_dp_axis(state: TrainState, dp: int) -> TrainState:
 
 
 def init_train_state(cfg: ModelConfig, tcfg: TrainConfig, tp: int, dp: int,
-                     key: jax.Array) -> TrainState:
-    return add_dp_axis(init_local_state(cfg, tcfg, tp, key), dp)
+                     key: jax.Array, shardings=None) -> TrainState:
+    """``shardings`` (a TrainState of NamedShardings): build the state in
+    one jit whose outputs land sharded, so each device only ever holds its
+    own replica instead of all ``dp`` broadcast onto the default device."""
+    def build(k):
+        return add_dp_axis(init_local_state(cfg, tcfg, tp, k), dp)
+
+    if shardings is None:
+        return build(key)
+    return jax.jit(build, out_shardings=shardings)(key)
 
 
 def squeeze_dp(state: TrainState) -> TrainState:

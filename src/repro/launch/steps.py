@@ -117,9 +117,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
         batch_spec["extra_emb"] = P(bdp, None, None)
     metrics_spec = {k: P() for k in ("loss", "xent", "aux", "synced",
                                      "grad_norm", "lr")}
-    f = mesh_lib.shard_map(local_step, mesh=mesh,
-                           in_specs=(state_spec, batch_spec),
-                           out_specs=(state_spec, metrics_spec))
+    f = jax.shard_map(local_step, mesh=mesh,
+                      in_specs=(state_spec, batch_spec),
+                      out_specs=(state_spec, metrics_spec), check_vma=False)
     return jax.jit(f, donate_argnums=(0,) if donate else ())
 
 
@@ -155,8 +155,8 @@ def make_prefill_step(cfg: ModelConfig, mesh, shape: InputShape,
         S.model_cache_pspecs(cfg, shape.global_batch, dp_total, long_ctx), dp_axes)
     bdp = bspec["ids"][0]
     out_specs = (P(bdp), cache_spec)
-    f = mesh_lib.shard_map(local_prefill, mesh=mesh,
-                           in_specs=(param_spec, bspec), out_specs=out_specs)
+    f = jax.shard_map(local_prefill, mesh=mesh, in_specs=(param_spec, bspec),
+                      out_specs=out_specs, check_vma=False)
     return jax.jit(f)
 
 
@@ -185,7 +185,7 @@ def make_serve_step(cfg: ModelConfig, mesh, shape: InputShape,
     cache_spec = S.resolve_tree(
         S.model_cache_pspecs(cfg, shape.global_batch, dp_total, long_ctx), dp_axes)
     bdp = bspec["pos"][0] if len(bspec["pos"]) else None
-    f = mesh_lib.shard_map(local_serve, mesh=mesh,
-                           in_specs=(param_spec, cache_spec, bspec),
-                           out_specs=(P(bdp), cache_spec))
+    f = jax.shard_map(local_serve, mesh=mesh,
+                      in_specs=(param_spec, cache_spec, bspec),
+                      out_specs=(P(bdp), cache_spec), check_vma=False)
     return jax.jit(f, donate_argnums=(1,))    # caches are update-in-place

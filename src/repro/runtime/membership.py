@@ -82,7 +82,7 @@ import numpy as np
 
 from repro.runtime import trace as trace_mod
 from repro.runtime.messages import (Channel, EpochBeginMsg, EpochMsg,
-                                    InstallMsg)
+                                    InstallMsg, PlanDoneMsg)
 
 log = logging.getLogger("repro.runtime.membership")
 
@@ -177,6 +177,10 @@ class MembershipManager:
         self._listeners: List[Callable] = []
         self._ctrl = [Channel(f"mm->s{s.sid}", s.inbox) for s in rt.shards]
         self._plan_thread: Optional[threading.Thread] = None
+        # a scripted plan with events left holds every shard's quiesce fin
+        # (wire transports): an op needs the clients' comm threads alive to
+        # ack its epoch, and a client exits once it holds every fin
+        self.plan_pending = False
 
     # ------------------------------------------------------------- listeners
     def add_listener(self, fn: Callable) -> None:
@@ -304,16 +308,40 @@ class MembershipManager:
     # ------------------------------------------------------------------ plan
     def start_plan(self, plan: MembershipPlan) -> None:
         """Launch the scripted-membership driver (called from start())."""
+        self.plan_pending = True
         self._plan_thread = threading.Thread(
             target=self._drive_plan, args=(plan,), name="ps-membership-plan",
             daemon=True)
         self._plan_thread.start()
 
+    def _clocks_final(self) -> bool:
+        """No further period can complete: the run failed or ended, or every
+        worker is done and the shards applied its last clock (wire
+        transports: every shard holds every process's ProcDone, which is
+        FIFO-after that process's last ClockMsg)."""
+        rt = self.rt
+        if rt._errors or rt._finished or not rt._started:
+            return True
+        if rt._proc_mode:
+            return (not rt.running
+                    or all(len(s._done_procs) == rt.n_proc
+                           for s in rt.shards))
+        return not rt.running and rt._inflight == 0
+
     def _drive_plan(self, plan: MembershipPlan) -> None:
+        try:
+            self._run_plan(plan)
+        finally:
+            self.plan_pending = False
+            if self.rt._proc_mode:
+                for chan in self._ctrl:
+                    self.rt._send(chan, PlanDoneMsg())
+
+    def _run_plan(self, plan: MembershipPlan) -> None:
         rt = self.rt
         for ev in plan.events:
             while rt.completed_clock() < ev.clock:
-                if not rt.running:
+                if self._clocks_final() and rt.completed_clock() < ev.clock:
                     plan.results.append((ev, "skipped"))
                     break
                 time.sleep(0.01)

@@ -277,6 +277,13 @@ class InstallMsg:
 
 
 @dataclass
+class PlanDoneMsg:
+    """Manager -> shard (in-parent only): the scripted membership plan has
+    run out, so a shard holding its quiesce fin for it re-checks now."""
+    seq: int = -1
+
+
+@dataclass
 class ProcDoneMsg:
     """Client process finished all its clocks: no more Update/Clock msgs
     (acks for in-flight deliveries may still follow).  Multi-process quiesce,

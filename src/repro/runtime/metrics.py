@@ -66,6 +66,7 @@ class ShardMetrics:
     publish_lag_s: float          # age of the oldest unpublished cycle
     updates_per_s: float = 0.0    # windowed: parts applied / s
     rows_per_s: float = 0.0       # windowed: row-updates applied / s
+    kernel_applies: int = 0       # batches applied by kernels/ps_apply
     # durability tier (repro.runtime.wal) — zeros when wal_dir is unset
     wal_parts: int = 0            # update parts logged (pending + written)
     wal_commits: int = 0          # group commits (clock boundaries hit)
@@ -286,6 +287,7 @@ class MetricsHub:
             inbox_depth=s.inbox.qsize(),
             parts_applied=parts,
             rows_applied=rows,
+            kernel_applies=int(s.m_kernel_applies),
             bytes_applied=int(s.m_bytes_applied),
             apply_lock_wait_s=float(s.m_lock_wait),
             applied_parts=[int(x) for x in s.applied_parts],

@@ -97,6 +97,7 @@ from repro.runtime.metrics import (LOAD_BLOCK_CLOCK, LOAD_BLOCK_VALUE,
                                    LOAD_LEN, LOAD_UPDATES, MetricsHub,
                                    RuntimeMetrics)
 from repro.runtime.shard import ServerShard
+from repro.runtime.snapshot import canonical_table
 
 _PROC_ALIAS = "shm"          # what transport="proc" resolves to
 
@@ -123,6 +124,13 @@ def _elastic_norms(unsynced: Dict[str, np.ndarray], key: str,
     new = cur + d2
     new_sq = sq - float(np.sum(cur * cur)) + float(np.sum(new * new))
     return math.sqrt(max(sq, 0.0)), math.sqrt(max(new_sq, 0.0))
+
+
+def _abs_totals(x0: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """Zeroed per-element sum-of-|delta| buffers, float32 tables only (a
+    float64 table's final check has a fixed tolerance and needs none)."""
+    return {k: np.zeros(v.shape) for k, v in x0.items()
+            if v.dtype == np.float32}
 
 
 class ClientProcess:
@@ -350,8 +358,8 @@ class _WorkerFlowMixin:
     (:class:`PSRuntime`, transport="queue") and the forked per-process host
     (:class:`_ClientHost`, wire transports).  Subclasses provide the state
     surface: ``procs``, ``policy``, ``stats``, ``_slock``, ``_total``,
-    ``_chan_ps``, ``_send``/``_send_many``/``_msg_done``, ``_next_uid``,
-    ``_check_alive``, ``_violation``, ``_record_error``,
+    ``_abs_total``, ``_chan_ps``, ``_send``/``_send_many``/``_msg_done``,
+    ``_next_uid``, ``_check_alive``, ``_violation``, ``_record_error``,
     ``_note_global_clock`` and the sizing/config attributes.
     """
 
@@ -364,7 +372,7 @@ class _WorkerFlowMixin:
                 self._clock_gate(w, clock, proc)
                 view = RuntimeViewHandle(self, proc, w)
                 upd = self.update_fn(w, clock, view, rng)
-                items = [(k, np.asarray(d, dtype=np.float64))
+                items = [(k, np.asarray(d, dtype=self._x0[k].dtype))
                          for k, d in upd.items()]
                 if self.prioritize and len(items) > 1:
                     # one magnitude pass per flush, then a stable descending
@@ -519,12 +527,15 @@ class _WorkerFlowMixin:
             proc.cache[key] += d2                       # read-my-writes
             acc = proc.unsynced[w][key]
             acc += d2
-            mag = float(np.max(np.abs(d2))) if d2.size else 0.0
+            ad = np.abs(d2)
+            mag = float(ad.max()) if d2.size else 0.0
             proc.m_updates += 1                         # (under proc.cond)
             with self._slock:
                 self.stats.n_updates += 1
                 self.stats.max_update_mag = max(self.stats.max_update_mag, mag)
                 self._total[key] += d2
+                if key in self._abs_total:
+                    self._abs_total[key] += ad
                 if blocked:
                     dt = time.monotonic() - t0
                     self.stats.block_time_value += dt
@@ -677,16 +688,14 @@ class PSRuntime(_WorkerFlowMixin):
         self._trace = (trace_mod.TraceHub(self._trace_cfg, "server")
                        if self.trace_on else None)
 
-        # canonical (R, C) float64 master shapes; original shapes for reads
+        # canonical (R, C) master tables (float32 or float64, see
+        # snapshot.canonical_table); original shapes for reads
         self._shapes: Dict[str, Tuple[int, ...]] = {}
         self._x0: Dict[str, np.ndarray] = {}
         self._row_counts: Dict[str, int] = {}
         for key, v in cfg.init_params.items():
-            a = np.asarray(v, dtype=np.float64)
-            self._shapes[key] = a.shape
-            flat = a.reshape(a.shape[0], -1) if a.ndim > 1 else a.reshape(-1, 1)
-            self._x0[key] = flat.copy()
-            self._row_counts[key] = flat.shape[0]
+            self._shapes[key], self._x0[key] = canonical_table(v)
+            self._row_counts[key] = self._x0[key].shape[0]
         self.partition = Partition(0, tuple(range(cfg.n_shards)),
                                    self._row_counts)
         # upper bound on one shard's in-stream bootstrap frame (publish
@@ -697,6 +706,9 @@ class PSRuntime(_WorkerFlowMixin):
         self.stats = RunStats()
         self._slock = threading.Lock()
         self._total = {k: np.zeros_like(v) for k, v in self._x0.items()}
+        # sum of |delta| per element of each float32 table: bounds its
+        # rounding at the final checks (see _agrees)
+        self._abs_total = _abs_totals(self._x0)
         # zero-lost/zero-duplicated audit: update parts sent, per process
         # (matched against the shards' applied_parts at the final checks)
         self._parts_sent = np.zeros(self.n_proc, dtype=np.int64)
@@ -1077,6 +1089,8 @@ class PSRuntime(_WorkerFlowMixin):
             self.stats.violations.extend(st.violations)
             for k, v in fin["total"].items():
                 self._total[k] += v
+            for k, v in fin["abs_total"].items():
+                self._abs_total[k] += v
             self._parts_sent[pid] = fin.get("parts_sent", 0)
             self._final_caches[pid] = fin["cache"]
             tr = fin.get("trace")
@@ -1327,6 +1341,21 @@ class PSRuntime(_WorkerFlowMixin):
             return self.snapshots[-1][1] if self.snapshots else None
 
     # ------------------------------------------------------------- checks
+    def _agrees(self, key: str, value: np.ndarray,
+                expected: np.ndarray) -> bool:
+        """``value`` equals ``expected`` up to the table dtype's rounding.
+        A float64 table keeps the fixed ``atol=1e-6``.  A float32 table
+        rounds every add, and two sequential float32 sums of the same n
+        terms in different orders differ by at most n * eps * sum|term|,
+        which is added to that tolerance."""
+        if self._x0[key].dtype != np.float32:
+            return np.allclose(value, expected, atol=1e-6)
+        n = self.stats.n_updates + 1
+        bound = n * float(np.finfo(np.float32).eps) * (
+            np.abs(self._x0[key]) + self._abs_total[key])
+        err = np.abs(value.astype(np.float64) - expected)
+        return bool(np.all(err <= 1e-6 + 1e-5 * np.abs(expected) + bound))
+
     def _final_checks(self) -> None:
         """Eventual consistency: caches and master equal x0 + sum(updates)."""
         expected = {k: self._x0[k] + self._total[k] for k in self._x0}
@@ -1334,12 +1363,12 @@ class PSRuntime(_WorkerFlowMixin):
                   else ((p, self.procs[p].cache) for p in range(self.n_proc)))
         for p, cache in caches:
             for k in self._x0:
-                if not np.allclose(cache[k], expected[k], atol=1e-6):
+                if not self._agrees(k, cache[k], expected[k]):
                     self._violation(
                         f"eventual-consistency violation on {k} (process {p})")
         for k in self._x0:
             master = self.master_value(k).reshape(self._x0[k].shape)
-            if not np.allclose(master, expected[k], atol=1e-6):
+            if not self._agrees(k, master, expected[k]):
                 self._violation(
                     f"eventual-consistency violation on {k} (shard tables)")
         # zero-lost/zero-duplicated audit across membership changes: every
@@ -1398,6 +1427,7 @@ class _ClientHost(_WorkerFlowMixin):
         self.stats = RunStats()
         self._slock = threading.Lock()
         self._total = {k: np.zeros_like(v) for k, v in self._x0.items()}
+        self._abs_total = _abs_totals(self._x0)
         self._parts_sent = np.zeros(rt.n_proc, dtype=np.int64)
         # globally unique uids without cross-process coordination
         self._uid = itertools.count(pid, rt.n_proc)
@@ -1536,6 +1566,7 @@ class _ClientHost(_WorkerFlowMixin):
             "pid": self.pid,
             "stats": self.stats,
             "total": self._total,
+            "abs_total": self._abs_total,
             "cache": self.proc.cache,
             "parts_sent": int(self._parts_sent[self.pid]),
             "trace": (self._trace.export() if self._trace is not None
@@ -1556,7 +1587,7 @@ def _client_child_main(rt: PSRuntime, pid: int, pipe) -> None:
         payload = host.run()
     except BaseException as e:
         payload = {"pid": pid, "stats": RunStats(), "total": {},
-                   "cache": {}, "errors": [repr(e)]}
+                   "abs_total": {}, "cache": {}, "errors": [repr(e)]}
     try:
         pipe.send(payload)
         pipe.close()

@@ -81,7 +81,8 @@ class Replica:
                 raise ValueError("bootstrap snapshot keys do not match "
                                  "the runtime's")
             self.values: Dict[str, np.ndarray] = {
-                k: master[k].astype(np.float64, copy=True) for k in rt._x0}
+                k: master[k].astype(rt._x0[k].dtype, copy=True)
+                for k in rt._x0}
             self.vc = SNAP.conservative_vc(seed_snapshot, rt.n_slots,
                                            rt.n_proc)
         else:

@@ -105,7 +105,8 @@ from repro.runtime.membership import INF_CLOCK
 from repro.runtime.messages import (SHUTDOWN, AckBatchMsg, AckMsg, Channel,
                                     ClockMarker, ClockMsg, DeliverMsg,
                                     EpochAckMsg, EpochBeginMsg, FullyDelivered,
-                                    InstallMsg, ProcDoneMsg, ReplicaDeltaMsg,
+                                    InstallMsg, PlanDoneMsg, ProcDoneMsg,
+                                    ReplicaDeltaMsg,
                                     ReplicaFinMsg, ReplicaStateMsg, ReplicaVcMsg,
                                     ShardFinMsg, SubscribeMsg, UnsubscribeMsg,
                                     UpdateMsg, group_by_channel, pump_inbox)
@@ -221,6 +222,7 @@ class ServerShard:
         # shard's thread bumps them, collectors read racily.  proc_load maps
         # pid -> (clock, counters) from the ClockMsg load piggyback.
         self.m_rows_applied = 0            # row-updates applied
+        self.m_kernel_applies = 0          # batches applied by the kernel
         self.m_bytes_applied = 0           # delta bytes applied
         self.m_lock_wait = 0.0             # cumulative dense-lock wait (s)
         self.m_last_publish = 0.0          # monotonic ts of last publish
@@ -383,6 +385,8 @@ class ServerShard:
             # ESSP: no further ClockMsg from this process will trigger a
             # boundary flush — release any backlog so the fin can drain
             self._flush_essp_hold()
+        elif isinstance(msg, PlanDoneMsg):
+            pass                              # the batch re-checks the fin
         else:
             raise TypeError(f"shard {self.sid}: unexpected message {msg!r}")
 
@@ -544,19 +548,21 @@ class ServerShard:
                         # the publish entry below retains m's arrays past
                         # this cycle: copy them out of the ring first
                         materialize_msg(m)
-                    # rows are unique within one part: plain fancy-index add
-                    dense[m.rows // A] += m.delta
                     rows, delta = m.rows, m.delta
                 else:
                     rows = np.concatenate([m.rows for m in msgs])
                     delta = np.concatenate([m.delta for m in msgs])
-                    # rows may repeat across parts: the scatter-add must
-                    # accumulate duplicates sequentially (np.add.at order)
-                    if use_kernels:
-                        from repro.kernels.ps_apply import ops as apply_ops
-                        apply_ops.scatter_add_inplace(dense, rows // A, delta)
-                    else:
-                        np.add.at(dense, rows // A, delta)
+                if use_kernels:
+                    # rows may repeat across parts: the kernel accumulates
+                    # duplicates sequentially (np.add.at order)
+                    from repro.kernels.ps_apply import ops as apply_ops
+                    self.m_kernel_applies += apply_ops.scatter_add_inplace(
+                        dense, rows // A, delta)
+                elif len(msgs) == 1:
+                    # rows are unique within one part: plain fancy-index add
+                    dense[rows // A] += delta
+                else:
+                    np.add.at(dense, rows // A, delta)
                 # serving: one coalesced delta per key per cycle per replica
                 # (global row ids; the arrays are shared — receivers only read)
                 for rid in self.subscribers:
@@ -688,12 +694,13 @@ class ServerShard:
         """Broadcast ShardFin once every process is done and deliveries have
         fully drained — nothing further will ever leave this shard.  A
         pending membership install (held messages still to replay) defers
-        the fin."""
+        the fin, and so does a scripted plan with events left: its ops
+        need every client still listening."""
         rt = self.rt
         if (self._fin_sent or len(self._done_procs) < rt.n_proc
                 or self.pending or any(self.queued.values())
                 or self._pending_part is not None or self._held
-                or self._essp_hold):
+                or self._essp_hold or rt.membership.plan_pending):
             return
         self._fin_sent = True
         for q in range(rt.n_proc):

@@ -35,6 +35,20 @@ import numpy as np
 SNAPSHOT_VERSION = 1
 
 
+def canonical_table(v) -> tuple:
+    """One ``init_params`` table as the runtime holds it: ``(shape, flat)``
+    with ``flat`` a fresh ``(R, C)`` buffer.  A table given as float32 stays
+    float32 (what a TPU block holds) and its updates accumulate in float32;
+    any other table is float64.  ``PSRuntime`` and :func:`recover_to_vc`
+    both build their buffers here, so a replay accumulates in the live
+    master's dtype."""
+    a = np.asarray(v)
+    a = a.astype(np.float32 if a.dtype == np.float32 else np.float64,
+                 copy=False)
+    flat = a.reshape(a.shape[0], -1) if a.ndim > 1 else a.reshape(-1, 1)
+    return a.shape, flat.copy()
+
+
 def take_snapshot(rt) -> dict:
     """Capture master shard state of a :class:`PSRuntime` (see module doc).
 
@@ -96,13 +110,16 @@ def take_snapshot(rt) -> dict:
 
 
 def assemble_master(snap: dict) -> Dict[str, np.ndarray]:
-    """Reassemble the full flat (R, C) master value per key."""
+    """Reassemble the full flat (R, C) master value per key, in the dtype
+    the shards held it."""
     shapes = snap["shapes"]
     out: Dict[str, np.ndarray] = {}
     for key, shape in shapes.items():
         r = shape[0] if len(shape) else 1
         c = int(np.prod(shape[1:])) if len(shape) > 1 else 1
-        full = np.zeros((r, c) if len(shape) else (1, 1), dtype=np.float64)
+        dtype = np.result_type(*(part[key]["values"]
+                                 for part in snap["shards"]))
+        full = np.zeros((r, c) if len(shape) else (1, 1), dtype=dtype)
         seen = 0
         for part in snap["shards"]:
             piece = part[key]
@@ -357,14 +374,11 @@ def recover_to_vc(init_params, wal_dir: str, *,
     from repro.runtime.transport import RowCodec
     from repro.runtime.wal import read_segment, wal_segments
 
-    # canonical flat (R, C) float64 buffers, exactly like PSRuntime.__init__
+    # canonical flat (R, C) buffers in the live master's dtype
     shapes: Dict[str, tuple] = {}
     flat: Dict[str, np.ndarray] = {}
     for key, v in init_params.items():
-        a = np.asarray(v, dtype=np.float64)
-        shapes[key] = a.shape
-        f = a.reshape(a.shape[0], -1) if a.ndim > 1 else a.reshape(-1, 1)
-        flat[key] = f.copy()
+        shapes[key], flat[key] = canonical_table(v)
     codec = RowCodec(list(init_params.keys()))
 
     upto = None

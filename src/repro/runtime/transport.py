@@ -26,7 +26,7 @@ share the stream, discriminated by the payload's first u32:
 
 * **raw row blocks** (shm data plane, :class:`RowCodec`)::
 
-      u32 RAW_MAGIC | u32 n_msgs | n_msgs * (hdr | rows int64 | delta f64)
+      u32 RAW_MAGIC | u32 n_msgs | n_msgs * (hdr | rows int64 | delta f64/f32)
 
   ``hdr`` is the fixed 48-byte struct ``_RAW_HDR`` (msg kind, dtype code,
   interned key id, uid/seq, worker/process/ts/shard/epoch, row and column
@@ -91,6 +91,8 @@ RAW_MAGIC = 0x46574152        # b"RAWF" little-endian; impossible n_buffers
 K_UPDATE = 1                  # raw msg kinds
 K_DELIVER = 2
 DT_F64 = 0                    # delta dtype codes (rows are always int64)
+DT_F32 = 1
+_RAW_DTYPES = (np.dtype(np.float64), np.dtype(np.float32))   # by code
 # kind u8 | dtype u8 | key id u16 | uid i64 | seq i64 |
 # worker, process, ts, shard, epoch, n_rows, n_cols i32  -> 48 bytes
 _RAW_HDR = struct.Struct("<BBHqqiiiiiii")
@@ -255,7 +257,7 @@ class RowCodec:
                 and isinstance(m.rows, np.ndarray)
                 and isinstance(m.delta, np.ndarray)
                 and m.rows.dtype == np.int64
-                and m.delta.dtype == np.float64
+                and m.delta.dtype in _RAW_DTYPES
                 and m.delta.ndim == 2)
 
     def _pack_raw(self, msgs: list) -> list:
@@ -267,7 +269,8 @@ class RowCodec:
             delta = np.ascontiguousarray(m.delta)
             kind = K_UPDATE if type(m) is UpdateMsg else K_DELIVER
             hdr = _RAW_HDR.pack(
-                kind, DT_F64, self._key_id[m.key], m.uid, m.seq,
+                kind, DT_F32 if delta.dtype == np.float32 else DT_F64,
+                self._key_id[m.key], m.uid, m.seq,
                 m.worker, m.process, m.ts, getattr(m, "shard", 0),
                 getattr(m, "epoch", 0), rows.shape[0], delta.shape[1])
             parts.append(hdr)
@@ -333,14 +336,15 @@ class RowCodec:
             (kind, dt, kid, uid, seq, worker, process, ts, shard, epoch,
              n_rows, n_cols) = _RAW_HDR.unpack_from(mv, off)
             off += _RAW_HDR.size
-            if dt != DT_F64:
+            if dt >= len(_RAW_DTYPES):
                 raise ValueError(f"unknown raw dtype code {dt}")
+            ddt = _RAW_DTYPES[dt]
             rows = np.frombuffer(mv, dtype=np.int64, count=n_rows,
                                  offset=off)
             off += n_rows * 8
-            delta = np.frombuffer(mv, dtype=np.float64, count=n_rows * n_cols,
+            delta = np.frombuffer(mv, dtype=ddt, count=n_rows * n_cols,
                                   offset=off).reshape(n_rows, n_cols)
-            off += n_rows * n_cols * 8
+            off += n_rows * n_cols * ddt.itemsize
             key = self._keys[kid]
             if kind == K_UPDATE:
                 m = UpdateMsg(uid, worker, process, ts, key, rows, delta,

@@ -18,10 +18,10 @@ A segment is a stream of the runtime's ordinary wire frames
 * **raw row-block frames** (:class:`~repro.runtime.transport.RowCodec`,
   the PR-6 zero-copy codec): the coalesced ``UpdateMsg`` runs of one apply
   cycle, each part's uid / origin process / ts / epoch / key / global row
-  ids / f64 deltas all in the fixed 48-byte struct header — nothing else
-  is needed to replay it with ``np.add.at`` onto a full-key buffer;
-* **pickle-5 frames** (the fallback for everything that is not an f64 row
-  block): the ``ReplicaVcMsg`` vc stamps, and any update part that is not
+  ids / f64 or f32 deltas all in the fixed 48-byte struct header — nothing
+  else is needed to replay it with ``np.add.at`` onto a full-key buffer;
+* **pickle-5 frames** (the fallback for everything that is not an f64/f32
+  row block): the ``ReplicaVcMsg`` vc stamps, and any update part that is not
   raw-eligible;
 * the ``EOF_LEN`` sentinel marks a *sealed* segment (clean close: seal at
   the epoch cut of a retiring slot, segment rotation, runtime teardown).

@@ -31,9 +31,9 @@ for arch in ["qwen3-8b", "gemma2-2b", "mamba2-130m", "recurrentgemma-9b",
     def fwd(p, ids):
         x, _, _ = M.forward(cfg, ctx, p, ids, remat=False)
         return ctx.gather_seq(x) if cfg.tp_strategy in ("head", "seq") else x
-    f = jax.jit(mesh_lib.shard_map(fwd, mesh=mesh,
+    f = jax.jit(jax.shard_map(fwd, mesh=mesh,
                 in_specs=(pspec_tree(defs), P("data", None)),
-                out_specs=P("data", None, None)))
+                out_specs=P("data", None, None), check_vma=False))
     xd = f(params, ids)
     params1 = instantiate_tree(M.model_defs(cfg, 1), jax.random.key(0))
     xl, _, _ = M.forward(cfg, ShardCtx(), params1, ids, remat=False)
@@ -217,9 +217,9 @@ for arch, strategy in [("olmo-1b", None), ("gemma2-2b", None),
         g = jax.tree.map(lambda x, rep: (jax.lax.psum(x, "model") if rep else x) / tp,
                          g, rep_mask)
         return jax.tree.map(lambda x: jax.lax.pmean(x, "data"), g)
-    g = jax.jit(mesh_lib.shard_map(grad_fn, mesh=mesh,
+    g = jax.jit(jax.shard_map(grad_fn, mesh=mesh,
                 in_specs=(pspec_tree(defs), P("data", None), P("data", None)),
-                out_specs=pspec_tree(defs)))(params, ids, labels)
+                out_specs=pspec_tree(defs), check_vma=False))(params, ids, labels)
     params1 = instantiate_tree(M.model_defs(cfg, 1), jax.random.key(0))
     def loss_l(p):
         return M.lm_loss(cfg, ShardCtx(), p, ids, labels, remat=False)[0]

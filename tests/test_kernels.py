@@ -217,6 +217,7 @@ PS_APPLY_CASES = [
     (8, 128, 8),
     (200, 3, 500),
     (17, 130, 64),    # C > one lane tile
+    (64, 8, 1500),    # N > ROW_BLK: several grid tiles, heavy duplicates
 ]
 
 
@@ -224,10 +225,10 @@ PS_APPLY_CASES = [
 @pytest.mark.parametrize("dtype", [np.float32, np.float64])
 def test_ps_apply_scatter_add(case, dtype):
     """Kernel must be BITWISE equal to np.add.at (same accumulation order)."""
-    from jax.experimental import enable_x64
     import contextlib
     R, C, N = case
-    ctx = enable_x64() if dtype == np.float64 else contextlib.nullcontext()
+    ctx = (jax.enable_x64(True) if dtype == np.float64
+           else contextlib.nullcontext())
     with ctx:
         dense = RNG.normal(0, 1, (R, C)).astype(dtype)
         rows = RNG.integers(0, R, N).astype(np.int32)
@@ -250,6 +251,21 @@ def test_ps_apply_dummy_row_is_noop():
     want = dense.copy()
     want[0] += 1
     want[5] += 1
+    got = np.asarray(pk.scatter_add_pallas(
+        jnp.asarray(dense), jnp.asarray(rows), jnp.asarray(delta),
+        interpret=True))
+    assert np.array_equal(got, want)
+
+
+def test_ps_apply_long_batch_splits_in_submission_order(monkeypatch):
+    """Batches longer than SMEM holds run as consecutive calls, bitwise."""
+    monkeypatch.setattr(pk, "SMEM_ROWS", 16)
+    R, C, N = 7, 3, 53
+    dense = RNG.normal(0, 1, (R, C)).astype(np.float32)
+    rows = RNG.integers(0, R, N).astype(np.int32)
+    delta = RNG.normal(0, 1, (N, C)).astype(np.float32)
+    want = dense.copy()
+    np.add.at(want, rows, delta)
     got = np.asarray(pk.scatter_add_pallas(
         jnp.asarray(dense), jnp.asarray(rows), jnp.asarray(delta),
         interpret=True))

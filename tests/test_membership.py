@@ -125,6 +125,30 @@ def test_membership_all_transports(transport):
         assert applied == int(rt._parts_sent.sum()) > 0
 
 
+@pytest.mark.parametrize("transport", ["queue", "shm", "tcp"])
+def test_plan_op_at_final_clock_runs_before_quiesce(transport):
+    """A scripted op whose clock the run reaches only once every worker
+    has finished still runs, during the quiesce: forked clients keep
+    listening (shards hold their fins for the plan) so the epoch barrier
+    completes, and an event past the last clock is skipped, not waited
+    for."""
+    seed = 5
+    n_clocks = 8
+    plan = MembershipPlan.parse([(n_clocks, "add", 2),
+                                 (n_clocks + 1, "remove", 0)])
+    rt = PSRuntime(RuntimeConfig(4, policies.ssp(2), x0(), n_shards=2,
+                   threads_per_process=2, seed=seed, max_shards=3,
+                   transport=transport, membership_plan=plan))
+    st = rt.run(det_fn(seed), n_clocks, timeout=60)
+    assert st.violations == [], st.violations[:5]
+    assert [r for _, r in plan.results] == ["ok", "skipped"], plan.results
+    assert rt.partition.active == (0, 1, 2)
+    for k, ref in expected_final(seed, 4, n_clocks).items():
+        np.testing.assert_array_equal(
+            rt.master_value(k).reshape(ref.shape), ref,
+            err_msg=f"{transport} master[{k}]")
+
+
 # ---------------------------------------------------------------------------
 # shrink to one, grow back, re-activate a retired slot
 # ---------------------------------------------------------------------------

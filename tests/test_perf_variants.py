@@ -68,9 +68,9 @@ ids = jnp.asarray(np.random.default_rng(0).integers(1, cfg.vocab_size, (4, 64)),
 def fwd(p, i):
     x, _, _ = M.forward(cfg, ctx, p, i, remat=False)
     return ctx.gather_seq(x)
-f = jax.jit(mesh_lib.shard_map(fwd, mesh=mesh,
+f = jax.jit(jax.shard_map(fwd, mesh=mesh,
             in_specs=(pspec_tree(defs), P("data", None)),
-            out_specs=P("data", None, None)))
+            out_specs=P("data", None, None), check_vma=False))
 xd = f(params, ids)
 cfg1 = dataclasses.replace(cfg, tp_strategy="replicated")
 params1 = instantiate_tree(M.model_defs(cfg1, 1), jax.random.key(0))
@@ -103,9 +103,9 @@ ids = jnp.asarray(np.random.default_rng(0).integers(1, cfg.vocab_size, (4, 32)),
 def fwd(p, i):
     x, _, _ = M.forward(cfg, ctx, p, i, remat=False)
     return ctx.gather_seq(x)
-f = jax.jit(mesh_lib.shard_map(fwd, mesh=mesh,
+f = jax.jit(jax.shard_map(fwd, mesh=mesh,
             in_specs=(pspec_tree(defs), P("data", None)),
-            out_specs=P("data", None, None)))
+            out_specs=P("data", None, None), check_vma=False))
 xd = f(params, ids)
 params1 = instantiate_tree(M.model_defs(cfg, 1), jax.random.key(0))
 cfg1 = dataclasses.replace(cfg, compress_gathers=False)

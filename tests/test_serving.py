@@ -236,6 +236,43 @@ def test_replica_joins_mid_run_equals_master_at_quiesce(tmp_path):
         gw.close()
 
 
+def test_f32_replica_seeded_from_snapshot_equals_master(tmp_path):
+    """A float32 table: a replica warm-started from a periodic snapshot
+    keeps float32 buffers and, with non-integer deltas, holds exactly the
+    master's float32 state at quiesce (no float64 drift)."""
+    x0 = {k: v.astype(np.float32) + np.float32(0.1) for k, v in _x0().items()}
+    rt = PSRuntime(RuntimeConfig(4, policies.ssp(3), x0, n_shards=2,
+                   threads_per_process=2, seed=13, snapshot_every=4,
+                   snapshot_dir=str(tmp_path)))
+    rt.start(_fn(pause=0.002), 30, timeout=120)
+    gw = ReadGateway(rt, n_replicas=1, transport="queue")
+    try:
+        deadline = time.monotonic() + 60
+        while rt.latest_snapshot() is None and rt.running:
+            assert time.monotonic() < deadline
+            time.sleep(2e-3)
+        assert rt.latest_snapshot() is not None
+        joined = gw.add_replica(bootstrap_from_snapshot=True)
+        assert all(v.dtype == np.float32 for v in joined.values.values())
+        st = rt.wait()
+        assert st.violations == []
+        deadline = time.monotonic() + 15
+        rset = gw.replicas
+        while rset.staleness(joined.vc, rset.master_vc()) > 0:
+            assert time.monotonic() < deadline, "joined replica never caught up"
+            time.sleep(5e-3)
+        for key in ("a", "b"):
+            value, _ = joined.serve(key)
+            master = rt.master_value(key)
+            assert value.dtype == master.dtype == np.float32
+            np.testing.assert_array_equal(
+                value.reshape(rt._shapes[key]), master,
+                err_msg=f"joined replica[{key}]")
+        assert gw.replicas.errors == []
+    finally:
+        gw.close()
+
+
 def test_poisoned_replica_leaves_the_rotation():
     """A replica whose ingest raised can no longer guarantee its vector
     clock covers its values: the gateway must never route to it again

@@ -587,6 +587,35 @@ def test_raw_wire_roundtrip_mixed_batch(seed):
         _close_zero_copy(ring, bell)
 
 
+def test_raw_wire_carries_f32_deltas():
+    """A float32 table's parts travel as raw frames too: f32 and f64 deltas
+    (odd widths, so later headers sit unaligned) decode in FIFO order with
+    their dtype, as views into the ring."""
+    rng = np.random.default_rng(4)
+    ring, codec, reader, chan, bell = _mk_zero_copy()
+    try:
+        msgs = []
+        for i in range(12):
+            rows = np.arange(int(rng.integers(1, 6)), dtype=np.int64)
+            dt = np.float32 if i % 3 else np.float64
+            delta = rng.normal(size=(len(rows), 3)).astype(dt)
+            msgs.append(M.UpdateMsg(i, 1, 0, i, "k", rows, delta))
+        assert all(codec._raw_ok(m) for m in msgs)
+        chan.send_many(msgs)
+        got = []
+        while len(got) < len(msgs):
+            got.extend(reader._decode_ready())
+        mem = _ring_mem(reader)
+        for a, b in zip(msgs, got):
+            _msg_equal(a, b)
+            assert np.shares_memory(b.delta, mem)
+        T.release_msgs(got)
+        got.clear()
+        del a, b, mem
+    finally:
+        _close_zero_copy(ring, bell)
+
+
 def test_zero_copy_views_alias_ring_until_released():
     """Raw frames decode as views INTO the ring; the shared head cursor
     holds at the pinned frame and only advances once every message from it

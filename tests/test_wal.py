@@ -304,6 +304,47 @@ def test_point_in_time_skips_uncovered_snapshot(tmp_path):
         np.testing.assert_array_equal(rec["params"][k], v)
 
 
+def test_f32_table_recovery_matches_live_master(tmp_path):
+    """A float32 table accumulates in float32 live and on replay: with
+    non-integer deltas (so every add rounds) genesis and snapshot-positioned
+    recovery both reproduce the live master bitwise, in float32, and the
+    log carries the parts as raw f32 frames."""
+    x0 = {"a": (np.arange(32, dtype=np.float32).reshape(8, 4) / 3),
+          "b": np.full(5, 0.1, dtype=np.float32)}
+
+    def fn(w, clock, view, rng):
+        return {"a": rng.normal(0.0, 0.7, size=(8, 4)),
+                "b": rng.normal(0.0, 0.7, size=5)}
+
+    sdir = str(tmp_path / "snaps")
+    wal_dir = str(tmp_path / "wal")
+    rt = PSRuntime(RuntimeConfig(4, policies.ssp(3), x0, n_shards=2,
+                                 threads_per_process=2, seed=21,
+                                 wal_dir=wal_dir, snapshot_every=4,
+                                 snapshot_dir=sdir))
+    st = rt.run(fn, n_clocks=14)
+    assert st.violations == []
+    live = {k: rt.master_value(k) for k in x0}
+    assert all(v.dtype == np.float32 for v in live.values())
+    gen = recover_to_vc(x0, wal_dir)
+    snap = recover_to_vc(x0, wal_dir, snapshot_dir=sdir)
+    assert snap["from_snapshot"] is not None
+    for rec in (gen, snap):
+        assert rec["applied_parts"].tolist() == rt._parts_sent.tolist()
+        for k, v in live.items():
+            assert rec["params"][k].dtype == np.float32
+            np.testing.assert_array_equal(rec["params"][k], v)
+    # the same deltas summed in float64 land elsewhere: the check has teeth
+    wide = recover_to_vc({k: v.astype(np.float64) for k, v in x0.items()},
+                         wal_dir)
+    assert not np.array_equal(wide["params"]["a"], live["a"])
+    codec = RowCodec(list(x0))
+    (_, path), *_ = wal_segments(wal_dir)[0]
+    records, _ = read_segment(path, codec)
+    parts = [m for kind, run in records if kind == "parts" for m in run]
+    assert parts and all(m.delta.dtype == np.float32 for m in parts)
+
+
 def test_double_replay_is_idempotent(tmp_path):
     """At-least-once replay: feeding the same log content twice (a segment
     duplicated under another generation name) changes nothing — the vc
