@@ -1,0 +1,10 @@
+"""Device time per train step of the ops under the program's
+``attention_core`` scope (attention's scores, mask, softmax and
+probabilities x V, per query chunk), in ms: leaf ops clipped to the traced
+window, averaged over the chips, over the window's steps
+(``bench/scopes.py``)."""
+from bench import scopes
+
+
+def read(run):
+    return scopes.step_ms(run, "attention_core")

@@ -1,0 +1,9 @@
+"""Device time per train step of the ops under the program's ``optimizer``
+scope (the optimizer's update over every parameter), in ms: leaf ops
+clipped to the traced window, averaged over the chips, over the window's
+steps (``bench/scopes.py``)."""
+from bench import scopes
+
+
+def read(run):
+    return scopes.step_ms(run, "optimizer")

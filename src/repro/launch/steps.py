@@ -4,6 +4,12 @@
 grads → local optimizer update → ``core.sync.apply_and_sync`` (read-my-writes
 apply + policy-triggered delta all-reduce over the data-parallel axes).
 
+Each layer of the train step runs under one ``jax.named_scope`` (``embed``,
+``blocks``, ``attention``, ``attention_core``, ``mlp``, ``lm_head`` in
+``models/``; ``optimizer``, ``sync``, ``step_metrics`` here), so every
+compiled op's ``op_name`` names its layer.  Scopes only label: the compiled
+step is the same program with or without them.
+
 Gradients of model-axis-replicated leaves (routers, norm scales, seq-TP
 projections) are psum'd over the model axis so replicated copies stay
 bitwise identical (Megatron rule); model-sharded leaves' grads are already
@@ -83,28 +89,35 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
                 lambda g, rep: (ctx.psum_model(g) if rep else g) / ctx.tp,
                 grads, rep_mask)
         lr = lr_fn(st.step)
-        update, opt = opt_fn(grads, st.opt, lr,
-                             weight_decay=tcfg.weight_decay, params=st.params)
-        params, sync_state, synced = apply_and_sync(
-            st.params, st.sync, update, policy, ctx.dp_axes,
-            compress="bf16" if tcfg.quantize_sync else None,
-            hierarchy=tcfg.hierarchical_sync, pod_axis=pod_axis,
-            trigger_axes=all_axes)
-        new = TrainState(params=params, opt=opt, sync=sync_state,
-                         step=st.step + 1)
-        out_metrics = {
-            "loss": loss.astype(jnp.float32),
-            "xent": metrics["xent"].astype(jnp.float32),
-            "aux": metrics["aux"],
-            "synced": synced.astype(jnp.float32),
-            "grad_norm": jnp.sqrt(sum(jnp.vdot(g, g).real
-                                      for g in jax.tree.leaves(grads))).astype(jnp.float32),
-            "lr": lr,
-        }
-        if all_axes:
-            out_metrics = jax.tree.map(
-                lambda m: lax.pmean(m, all_axes), out_metrics)
-        return unsqueeze_dp(new), out_metrics
+        with jax.named_scope("optimizer"):
+            update, opt = opt_fn(grads, st.opt, lr,
+                                 weight_decay=tcfg.weight_decay,
+                                 params=st.params)
+        with jax.named_scope("sync"):
+            params, sync_state, synced = apply_and_sync(
+                st.params, st.sync, update, policy, ctx.dp_axes,
+                compress="bf16" if tcfg.quantize_sync else None,
+                hierarchy=tcfg.hierarchical_sync, pod_axis=pod_axis,
+                trigger_axes=all_axes)
+            # in the scope: XLA roots the fused parameter and delta writes
+            # at this reshape, and a fusion is named by its root
+            new = unsqueeze_dp(TrainState(params=params, opt=opt,
+                                          sync=sync_state, step=st.step + 1))
+        with jax.named_scope("step_metrics"):
+            out_metrics = {
+                "loss": loss.astype(jnp.float32),
+                "xent": metrics["xent"].astype(jnp.float32),
+                "aux": metrics["aux"],
+                "synced": synced.astype(jnp.float32),
+                "grad_norm": jnp.sqrt(sum(
+                    jnp.vdot(g, g).real for g in jax.tree.leaves(grads)
+                )).astype(jnp.float32),
+                "lr": lr,
+            }
+            if all_axes:
+                out_metrics = jax.tree.map(
+                    lambda m: lax.pmean(m, all_axes), out_metrics)
+        return new, out_metrics
 
     if mesh is None:
         return jax.jit(local_step, donate_argnums=(0,) if donate else ())

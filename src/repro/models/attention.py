@@ -38,6 +38,7 @@ POS_SENTINEL = np.int32(2**30)   # k-slot "empty" marker (always masked out)
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("attention_core")
 def attention_core(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    q_pos: jnp.ndarray, k_pos: jnp.ndarray,
                    window: Optional[int] = None,

@@ -119,8 +119,10 @@ def block_fwd(cfg: ModelConfig, ctx: ShardCtx, mixer_ctx: ShardCtx,
     seqpar = pos is None
     h = apply_norm(cfg.norm_kind, x, p["ln1"])
     if meta.kind == "attn":
-        mix, new_cache = attn.attn_fwd(cfg, mixer_ctx, p["mix"], h,
-                                       window=meta.window, cache=cache, pos=pos)
+        with jax.named_scope("attention"):
+            mix, new_cache = attn.attn_fwd(cfg, mixer_ctx, p["mix"], h,
+                                           window=meta.window, cache=cache,
+                                           pos=pos)
     elif cfg.recurrent.kind == "rglru":
         mix, new_cache = rec.rglru_fwd(cfg, mixer_ctx, p["mix"], h,
                                        cache=cache, pos=pos)
@@ -133,10 +135,12 @@ def block_fwd(cfg: ModelConfig, ctx: ShardCtx, mixer_ctx: ShardCtx,
     aux = jnp.zeros((), jnp.float32)
     if meta.d_ff or meta.use_moe:
         h = apply_norm(cfg.norm_kind, x, p["ln2"])
-        if meta.use_moe:
-            y, aux = ffn.moe_fwd(cfg, mixer_ctx, p["ffn"], h)
-        else:
-            y = ffn.mlp_fwd(cfg, mixer_ctx, p["ffn"], h, sequence_parallel=seqpar)
+        with jax.named_scope("mlp"):
+            if meta.use_moe:
+                y, aux = ffn.moe_fwd(cfg, mixer_ctx, p["ffn"], h)
+            else:
+                y = ffn.mlp_fwd(cfg, mixer_ctx, p["ffn"], h,
+                                sequence_parallel=seqpar)
         if cfg.post_norm:
             y = apply_norm(cfg.norm_kind, y, p["post_ln2"])
         x = x + y
@@ -275,7 +279,8 @@ def forward(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray, *,
     seq_sharded = (pos is None
                    and cfg.tp_strategy in ("head", "seq", "seq_ssm")
                    and ctx.model_axis is not None)
-    x = embed_tokens(cfg, ctx, params, ids, seq_shard=seq_sharded)
+    with jax.named_scope("embed"):
+        x = embed_tokens(cfg, ctx, params, ids, seq_shard=seq_sharded)
     if pos is None:
         s_loc = x.shape[1]
         positions = (ctx.index() * s_loc if seq_sharded else 0) + jnp.arange(
@@ -288,76 +293,77 @@ def forward(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray, *,
     def run_block(meta, p, x, cache):
         return block_fwd(cfg, ctx, mctx, meta, p, x, cache=cache, pos=pos)
 
-    # --- prefix (unrolled) ----------------------------------------------------
-    for i, meta in enumerate(prefix):
-        c = caches["prefix"][i] if caches is not None else None
-        x, nc, aux = run_block(meta, params["prefix"][i], x, c)
-        aux_total += aux
-        new_caches["prefix"].append(nc)
+    with jax.named_scope("blocks"):
+        # --- prefix (unrolled) ----------------------------------------------
+        for i, meta in enumerate(prefix):
+            c = caches["prefix"][i] if caches is not None else None
+            x, nc, aux = run_block(meta, params["prefix"][i], x, c)
+            aux_total += aux
+            new_caches["prefix"].append(nc)
 
-    # --- scanned units ----------------------------------------------------------
-    if n_units and unroll:
-        # python-loop over units: big HLO, but per-layer FLOPs/collectives
-        # appear explicitly (cost_analysis counts while-loop bodies ONCE, so
-        # the dry-run/roofline lowers this form — EXPERIMENTS.md §Dry-run)
-        unit_params = params["scan"]
-        body = (lambda f: jax.checkpoint(f)) if remat else (lambda f: f)
-        def unit_fn(x, aux_acc, p_unit, c_unit):
-            ncs = []
-            for j, meta in enumerate(unit):
-                x, nc, aux = run_block(meta, p_unit[j], x, c_unit[j])
-                aux_acc = aux_acc + aux
-                ncs.append(nc)
-            return x, aux_acc, ncs
-
-        for u in range(n_units):
-            p_unit = jax.tree.map(lambda a: a[u], unit_params)
-            c_unit = (jax.tree.map(lambda a: a[u], caches["scan"])
-                      if caches is not None else [None] * len(unit))
-            x, aux_total, ncs = body(unit_fn)(x, aux_total, p_unit, c_unit)
-            if caches is not None:
-                new_caches["scan"].append(ncs)
-        if caches is not None:
-            # restack unit caches to the (n_units, ...) layout scan produces
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs, 0),
-                                   *new_caches["scan"])
-            new_caches["scan"] = stacked
-    elif n_units:
-        unit_params = params["scan"]
-        if caches is None:
-
-            def unit_body(carry, p_unit):
-                x, aux_acc = carry
-                for j, meta in enumerate(unit):
-                    x, _, aux = run_block(meta, p_unit[j], x, None)
-                    aux_acc = aux_acc + aux
-                return (x, aux_acc), None
-
-            body = jax.checkpoint(unit_body) if remat else unit_body
-            (x, aux_total), _ = lax.scan(body, (x, aux_total), unit_params)
-        else:
-
-            def unit_body_c(carry, xs_):
-                x, aux_acc = carry
-                p_unit, c_unit = xs_
+        # --- scanned units --------------------------------------------------
+        if n_units and unroll:
+            # python-loop over units: big HLO, but per-layer FLOPs/collectives
+            # appear explicitly (cost_analysis counts while-loop bodies ONCE, so
+            # the dry-run/roofline lowers this form — EXPERIMENTS.md §Dry-run)
+            unit_params = params["scan"]
+            body = (lambda f: jax.checkpoint(f)) if remat else (lambda f: f)
+            def unit_fn(x, aux_acc, p_unit, c_unit):
                 ncs = []
                 for j, meta in enumerate(unit):
                     x, nc, aux = run_block(meta, p_unit[j], x, c_unit[j])
                     aux_acc = aux_acc + aux
                     ncs.append(nc)
-                return (x, aux_acc), ncs
+                return x, aux_acc, ncs
 
-            body = jax.checkpoint(unit_body_c) if remat else unit_body_c
-            (x, aux_total), scan_caches = lax.scan(
-                body, (x, aux_total), (unit_params, caches["scan"]))
-            new_caches["scan"] = scan_caches
+            for u in range(n_units):
+                p_unit = jax.tree.map(lambda a: a[u], unit_params)
+                c_unit = (jax.tree.map(lambda a: a[u], caches["scan"])
+                          if caches is not None else [None] * len(unit))
+                x, aux_total, ncs = body(unit_fn)(x, aux_total, p_unit, c_unit)
+                if caches is not None:
+                    new_caches["scan"].append(ncs)
+            if caches is not None:
+                # restack unit caches to the (n_units, ...) layout scan produces
+                stacked = jax.tree.map(lambda *xs: jnp.stack(xs, 0),
+                                       *new_caches["scan"])
+                new_caches["scan"] = stacked
+        elif n_units:
+            unit_params = params["scan"]
+            if caches is None:
 
-    # --- tail (unrolled) ----------------------------------------------------------
-    for i, meta in enumerate(tail):
-        c = caches["tail"][i] if caches is not None else None
-        x, nc, aux = run_block(meta, params["tail"][i], x, c)
-        aux_total += aux
-        new_caches["tail"].append(nc)
+                def unit_body(carry, p_unit):
+                    x, aux_acc = carry
+                    for j, meta in enumerate(unit):
+                        x, _, aux = run_block(meta, p_unit[j], x, None)
+                        aux_acc = aux_acc + aux
+                    return (x, aux_acc), None
+
+                body = jax.checkpoint(unit_body) if remat else unit_body
+                (x, aux_total), _ = lax.scan(body, (x, aux_total), unit_params)
+            else:
+
+                def unit_body_c(carry, xs_):
+                    x, aux_acc = carry
+                    p_unit, c_unit = xs_
+                    ncs = []
+                    for j, meta in enumerate(unit):
+                        x, nc, aux = run_block(meta, p_unit[j], x, c_unit[j])
+                        aux_acc = aux_acc + aux
+                        ncs.append(nc)
+                    return (x, aux_acc), ncs
+
+                body = jax.checkpoint(unit_body_c) if remat else unit_body_c
+                (x, aux_total), scan_caches = lax.scan(
+                    body, (x, aux_total), (unit_params, caches["scan"]))
+                new_caches["scan"] = scan_caches
+
+        # --- tail (unrolled) ------------------------------------------------
+        for i, meta in enumerate(tail):
+            c = caches["tail"][i] if caches is not None else None
+            x, nc, aux = run_block(meta, params["tail"][i], x, c)
+            aux_total += aux
+            new_caches["tail"].append(nc)
 
     x = apply_norm(cfg.norm_kind, x, params["final_norm"])
     return x, (new_caches if caches is not None else None), aux_total
@@ -385,53 +391,54 @@ def lm_loss(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray,
     """
     x, _, aux = forward(cfg, ctx, params, ids, extra_emb=extra_emb,
                         remat=remat, unroll=unroll)
-    w = head_matrix(cfg, params).astype(x.dtype)
+    with jax.named_scope("lm_head"):
+        w = head_matrix(cfg, params).astype(x.dtype)
 
-    # Vocab-parallel cross-entropy: logits are vocab-sharded, so every model
-    # shard needs ALL tokens — gather the sequence-sharded residual first,
-    # then reduce the logsumexp over the model axis.
-    seq_sharded = (cfg.tp_strategy in ("head", "seq", "seq_ssm")
-                   and ctx.model_axis is not None)
-    if seq_sharded:
-        x = ctx.gather_seq(x, compress=cfg.compress_gathers)
-    b, s, d = x.shape
+        # Vocab-parallel cross-entropy: logits are vocab-sharded, so every model
+        # shard needs ALL tokens — gather the sequence-sharded residual first,
+        # then reduce the logsumexp over the model axis.
+        seq_sharded = (cfg.tp_strategy in ("head", "seq", "seq_ssm")
+                       and ctx.model_axis is not None)
+        if seq_sharded:
+            x = ctx.gather_seq(x, compress=cfg.compress_gathers)
+        b, s, d = x.shape
 
-    n_chunks = max(1, s // chunk)
-    cs = s // n_chunks
-    xs = x[:, :n_chunks * cs].reshape(b, n_chunks, cs, d).swapaxes(0, 1)
-    ls = labels[:, :n_chunks * cs].reshape(b, n_chunks, cs).swapaxes(0, 1)
+        n_chunks = max(1, s // chunk)
+        cs = s // n_chunks
+        xs = x[:, :n_chunks * cs].reshape(b, n_chunks, cs, d).swapaxes(0, 1)
+        ls = labels[:, :n_chunks * cs].reshape(b, n_chunks, cs).swapaxes(0, 1)
 
-    vloc = w.shape[1]
-    start = ctx.index() * vloc
+        vloc = w.shape[1]
+        start = ctx.index() * vloc
 
-    def chunk_loss(xc, lc):
-        logits = (xc @ w).astype(jnp.float32)                 # (b, cs, V_loc)
-        if cfg.final_softcap is not None:
-            logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
-        # max-shift is a constant wrt the gradient (softmax is shift
-        # invariant) — pmax has no JVP rule, so sever the tangent first
-        mx = ctx.pmax_model(lax.stop_gradient(logits.max(-1)))
-        se = ctx.psum_model(jnp.exp(logits - mx[..., None]).sum(-1))
-        lse = mx + jnp.log(se)
-        loc = lc - start
-        ok = (loc >= 0) & (loc < vloc)
-        ll = jnp.take_along_axis(logits, jnp.clip(loc, 0, vloc - 1)[..., None],
-                                 axis=-1)[..., 0]
-        ll = ctx.psum_model(jnp.where(ok, ll, 0.0))
-        mask = (lc >= 0).astype(jnp.float32)
-        return jnp.sum((lse - ll) * mask), jnp.sum(mask)
+        def chunk_loss(xc, lc):
+            logits = (xc @ w).astype(jnp.float32)                 # (b, cs, V_loc)
+            if cfg.final_softcap is not None:
+                logits = jnp.tanh(logits / cfg.final_softcap) * cfg.final_softcap
+            # max-shift is a constant wrt the gradient (softmax is shift
+            # invariant) — pmax has no JVP rule, so sever the tangent first
+            mx = ctx.pmax_model(lax.stop_gradient(logits.max(-1)))
+            se = ctx.psum_model(jnp.exp(logits - mx[..., None]).sum(-1))
+            lse = mx + jnp.log(se)
+            loc = lc - start
+            ok = (loc >= 0) & (loc < vloc)
+            ll = jnp.take_along_axis(logits, jnp.clip(loc, 0, vloc - 1)[..., None],
+                                     axis=-1)[..., 0]
+            ll = ctx.psum_model(jnp.where(ok, ll, 0.0))
+            mask = (lc >= 0).astype(jnp.float32)
+            return jnp.sum((lse - ll) * mask), jnp.sum(mask)
 
-    fn = jax.checkpoint(chunk_loss) if remat else chunk_loss
+        fn = jax.checkpoint(chunk_loss) if remat else chunk_loss
 
-    def body(acc, inp):
-        l, n = fn(*inp)
-        return (acc[0] + l, acc[1] + n), None
+        def body(acc, inp):
+            l, n = fn(*inp)
+            return (acc[0] + l, acc[1] + n), None
 
-    (tot, n), _ = lax.scan(body, (jnp.zeros((), jnp.float32),
-                                  jnp.zeros((), jnp.float32)), (xs, ls))
-    # after the gather every model shard summed over the SAME tokens (the
-    # per-token lse/ll were completed with psum inside chunk_loss)
-    loss = tot / jnp.maximum(n, 1.0)
+        (tot, n), _ = lax.scan(body, (jnp.zeros((), jnp.float32),
+                                      jnp.zeros((), jnp.float32)), (xs, ls))
+        # after the gather every model shard summed over the SAME tokens (the
+        # per-token lse/ll were completed with psum inside chunk_loss)
+        loss = tot / jnp.maximum(n, 1.0)
     metrics = {"xent": loss, "aux": aux, "tokens": n}
     if cfg.moe is not None:
         loss = loss + cfg.moe.router_aux_coef * aux
