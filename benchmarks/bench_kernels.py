@@ -31,8 +31,7 @@ def run() -> List[Dict]:
     q = jnp.asarray(RNG.normal(0, 1, (b, s, kvh, G, dh)), jnp.float32)
     k = jnp.asarray(RNG.normal(0, 1, (b, s, kvh, dh)), jnp.float32)
     v = jnp.asarray(RNG.normal(0, 1, (b, s, kvh, dh)), jnp.float32)
-    pos = jnp.arange(s, dtype=jnp.int32)
-    us = _time(lambda: fa.flash_attention(q, k, v, pos, pos, window=512))
+    us = _time(lambda: fa.flash_attention(q, k, v, window=512))
     flops = 4 * b * kvh * G * s * 512 * dh   # banded
     rows.append({"name": "kernel_ref/flash_attention_2k_w512",
                  "us_per_call": us, "derived_gflops": flops / us / 1e3})

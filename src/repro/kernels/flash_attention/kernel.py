@@ -1,17 +1,23 @@
-"""Flash-attention Pallas kernel: online-softmax blocked attention.
+"""Causal flash attention on the TPU, forward and backward: the block-sparse
+splash kernel that ships with jax
+(``jax.experimental.pallas.ops.tpu.splash_attention``).
 
-Grid: (batch·q_heads, q_blocks, k_blocks) — the k axis is innermost, so the
-running max / denominator / accumulator live in VMEM scratch and carry
-across k blocks (TPU grids are sequential).  Per grid step the VMEM working
-set is q (BLK_Q × dh) + k/v (BLK_K × dh) + acc (BLK_Q × dh f32) + the
-(BLK_Q × BLK_K) score tile — ≲ 1 MiB at the default 128/512 blocks, and all
-matmul dims are 128-aligned for the MXU.
+Each query block keeps its running max and denominator in f32 VMEM and
+walks only the key blocks its mask reaches: blocks wholly above the
+diagonal (or wholly left of a sliding window) are neither fetched nor
+computed, in the forward and in both backward kernels (dq, and dk/dv).  No
+score or probability block is written to HBM; the forward saves the
+per-row logsumexp for the backward.
 
-Supports: causal masking by absolute positions, sliding windows, logit
-softcap, GQA (kv head = q head // group), separate v head dim (MLA).
-Out-of-window k blocks are skipped with ``pl.when`` on the block position
-bounds — this is where the TPU kernel beats the jnp oracle's banded-chunk
-approximation.
+Precision: q·kᵀ takes q and k in their own dtype (bf16 in the model) with
+f32 accumulation; the softmax statistics are f32; the backward's products
+take P and dS rounded to that dtype, with f32 accumulation.  The kernel
+applies no 1/√dh, so it is folded into q here.
+
+Block sizes follow the sequence length: the largest of :data:`BLOCKS` that
+divides it, for every block of the forward and the backward.  The mask
+information is built once per (length, heads, window, cap) and reused by
+every call.
 """
 from __future__ import annotations
 
@@ -21,124 +27,62 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu.splash_attention import (
+    splash_attention_kernel as splash, splash_attention_mask as masks)
 
-BLK_Q = 128
-BLK_K = 512
-NEG_INF = -1e30
+BLOCKS = (512, 256, 128)
 
 
-def _kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, scale: float, cap: Optional[float],
-            window: Optional[int], nk: int):
-    ik = pl.program_id(2)
+def block_size(s: int) -> Optional[int]:
+    """The block for sequence length ``s``, or None where none divides it."""
+    return next((b for b in BLOCKS if s % b == 0), None)
 
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qp = qp_ref[...]                       # (BLK_Q,) absolute q positions
-    kp = kp_ref[...]                       # (BLK_K,) absolute k positions
+def takes(s: int, dh: int, dv: int) -> bool:
+    """Whether the kernel runs this sequence length and these head dims."""
+    return block_size(s) is not None and dh == dv and dh % 128 == 0
 
-    # block-level skip: any (q, k) pair in range?
-    q_lo, q_hi = jnp.min(qp), jnp.max(qp)
-    k_lo = jnp.min(kp)
-    may_attend = k_lo <= q_hi
-    if window is not None:
-        k_hi = jnp.max(kp)
-        may_attend &= k_hi > (q_lo - window)
 
-    @pl.when(may_attend)
-    def _block():
-        q = q_ref[0].astype(jnp.float32)                     # (BLK_Q, dh)
-        k = k_ref[0].astype(jnp.float32)                     # (BLK_K, dh)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if cap is not None:
-            s = jnp.tanh(s / cap) * cap
-        mask = kp[None, :] <= qp[:, None]
-        if window is not None:
-            mask &= kp[None, :] > (qp[:, None] - window)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_ref[...]                                  # (BLK_Q, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)                     # (BLK_K, dv)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(ik == nk - 1)
-    def _finalize():
-        l = l_ref[...]
-        o = acc_ref[...] / jnp.where(l > 0, l, 1.0)
-        o_ref[...] = o[None].astype(o_ref.dtype)
+@functools.lru_cache(maxsize=None)
+def _splash(s: int, heads: int, mqa: bool, window: Optional[int],
+            cap: Optional[float], interpret: bool):
+    blk = block_size(s)
+    sizes = splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        block_q_dq=blk, block_kv_dq=blk)
+    one = (masks.CausalMask((s, s)) if window is None else
+           masks.LocalMask((s, s), (window - 1, 0), offset=0))
+    make = splash.make_splash_mqa if mqa else splash.make_splash_mha
+    # concrete mask arrays even when first built inside a traced step
+    with jax.ensure_compile_time_eval():
+        return make(masks.MultiHeadMask([one] * heads), block_sizes=sizes,
+                    attn_logits_soft_cap=cap, head_shards=1, q_seq_shards=1,
+                    interpret=interpret)
 
 
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                           q_pos: jnp.ndarray, k_pos: jnp.ndarray,
                            window: Optional[int] = None,
                            cap: Optional[float] = None,
                            interpret: bool = False) -> jnp.ndarray:
-    """q: (b, sq, kvh, G, dh); k, v: (b, skv, kvh, dh_{k,v});
-    q_pos: (sq,) or (b, sq) — must be batch-independent for the kernel, so
-    only (sq,) is accepted; k_pos: (skv,)."""
-    if q_pos.ndim != 1 or k_pos.ndim != 1:
-        raise ValueError("flash kernel expects shared (sq,)/(skv,) positions")
-    b, sq, kvh, G, dh = q.shape
-    skv = k.shape[1]
-    dv = v.shape[-1]
-    scale = 1.0 / np.sqrt(dh)
+    """Causal self-attention over positions 0..s-1 on both sides: query i
+    attends to keys j <= i (and j > i - window).
 
-    blk_q = min(BLK_Q, max(8, sq))
-    blk_k = min(BLK_K, max(128, skv))
-    pad_q = (-sq) % blk_q
-    pad_k = (-skv) % blk_k
-    SENT = np.int32(2**30)
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0), (0, 0), (0, 0)))
-        q_pos = jnp.pad(q_pos, (0, pad_q), constant_values=0)
-    if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        k_pos = jnp.pad(k_pos, (0, pad_k), constant_values=SENT)
-    sqp, skp = sq + pad_q, skv + pad_k
-
-    # fold heads: q -> (BH, sqp, dh) with BH = b*kvh*G; k index = BH // G
-    qf = q.transpose(0, 2, 3, 1, 4).reshape(b * kvh * G, sqp, dh)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * kvh, skp, dh)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * kvh, skp, dv)
-
-    nq, nk = sqp // blk_q, skp // blk_k
-    grid = (b * kvh * G, nq, nk)
-
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, cap=cap, window=window, nk=nk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((blk_q,), lambda ih, iq, ik: (iq,)),
-            pl.BlockSpec((blk_k,), lambda ih, iq, ik: (ik,)),
-            pl.BlockSpec((1, blk_q, dh), lambda ih, iq, ik: (ih, iq, 0)),
-            pl.BlockSpec((1, blk_k, dh), lambda ih, iq, ik: (ih // G, ik, 0)),
-            pl.BlockSpec((1, blk_k, dv), lambda ih, iq, ik: (ih // G, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, blk_q, dv), lambda ih, iq, ik: (ih, iq, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * kvh * G, sqp, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, 1), jnp.float32),
-            pltpu.VMEM((blk_q, 1), jnp.float32),
-            pltpu.VMEM((blk_q, dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q_pos.astype(jnp.int32), k_pos.astype(jnp.int32), qf, kf, vf)
-
-    out = out.reshape(b, kvh, G, sqp, dv).transpose(0, 3, 1, 2, 4)
-    return out[:, :sq]
+    q: (b, s, kvh, G, dh) — G query heads per kv head; k, v: (b, s, kvh, dh).
+    G == 1 runs the multi-head kernel over the kv heads; G > 1 the
+    multi-query kernel per kv head, over its G query heads.
+    """
+    b, s, kvh, G, dh = q.shape
+    if not takes(s, dh, v.shape[-1]) or k.shape[1] != s:
+        raise ValueError(f"flash kernel does not take q {q.shape}, "
+                         f"k {k.shape}, v {v.shape}")
+    q = (q * (1.0 / np.sqrt(dh))).astype(q.dtype)
+    kt = k.transpose(0, 2, 1, 3)                             # (b, kvh, s, dh)
+    vt = v.transpose(0, 2, 1, 3)
+    if G == 1:
+        kern = _splash(s, kvh, False, window, cap, interpret)
+        o = jax.vmap(kern)(q[:, :, :, 0].transpose(0, 2, 1, 3), kt, vt)
+        return o.transpose(0, 2, 1, 3)[:, :, :, None]
+    kern = _splash(s, G, True, window, cap, interpret)
+    o = jax.vmap(jax.vmap(kern))(q.transpose(0, 2, 3, 1, 4), kt, vt)
+    return o.transpose(0, 3, 1, 2, 4)                        # (b, s, kvh, G, dh)

@@ -2,7 +2,7 @@
 
 Dense masked attention in f32 — deliberately the simplest correct thing.
 Matches the model-side chunked core (repro.models.attention.attention_core);
-tests assert ref == chunked core == Pallas kernel.
+tests assert ref == chunked core == Pallas kernel, outputs and gradients.
 """
 from __future__ import annotations
 

@@ -11,10 +11,12 @@ Layouts (DESIGN.md §6) — the residual stream is always sequence-sharded
   collective.  Decode shards the KV cache over the model axis by *slot* and
   combines partial attention with a distributed logsumexp.
 
-The jnp attention core is the oracle the Pallas flash kernel is validated
-against; on CPU (and in the dry-run) the core itself runs, chunked over
-query blocks and *banded* for sliding windows so compiled FLOPs/memory stay
-honest.
+Causal self-attention over a whole sequence (train and prefill without
+seq-TP) runs the Pallas flash kernel wherever Pallas is on (or interpreted)
+and the kernel takes the shapes (``kernels/flash_attention``); every other
+call, and every call with Pallas off (the CPU default), runs the jnp core,
+chunked over query blocks and *banded* for sliding windows so compiled
+FLOPs/memory stay honest.
 """
 from __future__ import annotations
 
@@ -26,6 +28,7 @@ import numpy as np
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.models.common import (ParamDef, ShardCtx, apply_rope, kv_eff_heads,
                                  softcap)
 
@@ -50,7 +53,13 @@ def attention_core(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     k,v: (b, skv, kvh, dh)
     q_pos: (sq,) or (b, sq); k_pos: (skv,) or (b, skv) — absolute positions;
     mask = (k_pos <= q_pos) & (k_pos > q_pos - window).
+
+    Where both positions are 0..s-1, known while tracing, the flash kernel
+    takes the call if it can (``fa_ops.kernel_takes``); else the jnp core
+    below runs.
     """
+    if fa_ops.kernel_takes(q, k, v, q_pos, k_pos):
+        return fa_ops.flash_attention(q, k, v, window=window, cap=cap)
     b, sq, kvh, G, dh = q.shape
     skv = k.shape[1]
     scale = 1.0 / np.sqrt(dh)
@@ -98,13 +107,6 @@ def attention_core(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     out = lax.map(per_chunk, jnp.arange(n_chunks))          # (n, b, chunk, ...)
     return jnp.moveaxis(out, 0, 1).reshape(b, sq, kvh, G, v.shape[-1])
-
-
-def attention_core_dispatch(*args, **kw):
-    """Hook point: the Pallas flash-attention kernel replaces this on TPU
-    (see repro.kernels.flash_attention.ops)."""
-    from repro.kernels.flash_attention import ops as fa_ops
-    return fa_ops.flash_attention(*args, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -244,12 +246,12 @@ def _gqa_full(cfg, ctx, p, x, *, window, cache):
         q = _split_heads(xg @ p["wq"], hq_loc, dh)
         k = _split_heads(xg @ p["wk"], kv_loc, dh)
         v = _split_heads(xg @ p["wv"], kv_loc, dh)
-        positions = jnp.arange(s, dtype=jnp.int32)
+        positions = np.arange(s, dtype=np.int32)     # known while tracing
         q_pos = k_pos = positions
     else:
         hq_loc, kv_loc = cfg.n_heads, cfg.n_kv_heads
         local_pos = (ctx.index() * s_loc + jnp.arange(s_loc, dtype=jnp.int32)
-                     if seq_tp else jnp.arange(s_loc, dtype=jnp.int32))
+                     if seq_tp else np.arange(s_loc, dtype=np.int32))
         q = _split_heads(x @ p["wq"], hq_loc, dh)
         k_loc = _split_heads(x @ p["wk"], kv_loc, dh)
         v_loc = _split_heads(x @ p["wv"], kv_loc, dh)
@@ -261,7 +263,7 @@ def _gqa_full(cfg, ctx, p, x, *, window, cache):
         k = ctx.gather_seq(k_loc) if seq_tp else k_loc       # (b, s, kv, dh)
         v = ctx.gather_seq(v_loc) if seq_tp else v_loc
         q_pos = local_pos
-        k_pos = jnp.arange(s, dtype=jnp.int32)
+        k_pos = np.arange(s, dtype=np.int32)
 
     if head_tp:
         if cfg.qk_norm:

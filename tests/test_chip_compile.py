@@ -89,3 +89,37 @@ def test_rglru_scan_compiles_at_recurrentgemma_width(one_chip,
     lam = _sds((4096,), jnp.float32, one_chip)
     compiled = jax.jit(kernel.rglru_pallas).lower(x, x, x, lam).compile()
     assert _has_kernel(compiled)
+
+
+def test_flash_attention_compiles_at_the_train_cell_shape(
+        one_chip, no_persistent_cache, monkeypatch):
+    """``olmo-1b-4l.cvap3``'s attention, (8, 2048, 16, 128) bf16 causal,
+    forward and backward through the model's ``attention_core``: the
+    forward, dq and dk/dv kernels are in the program, and each carries the
+    ``attention_core`` scope in its ``op_name``, so that its device time
+    counts in ``step_ms.attention_core``."""
+    import re
+
+    import numpy as np
+    monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from bench import scopes
+    monkeypatch.setenv("REPRO_PALLAS", "on")
+    from repro.models.attention import attention_core
+    pos = np.arange(2048, dtype=np.int32)
+
+    def loss(q, k, v):
+        return jnp.sum(attention_core(q, k, v, pos, pos).astype(jnp.float32))
+
+    kv = _sds((8, 2048, 16, 128), jnp.bfloat16, one_chip)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        _sds((8, 2048, 16, 1, 128), jnp.bfloat16, one_chip), kv, kv).compile()
+    # a kernel's instruction spans lines (its kernel metadata holds one)
+    text = compiled.as_text()
+    names = [re.compile(r'op_name="([^"]*)"').search(text, m.end()).group(1)
+             for m in re.finditer(r'custom_call_target="tpu_custom_call"',
+                                  text)]
+    assert len(names) == 3, names
+    for part in ("fwd", "dq", "dkv"):
+        assert any(part in n.rsplit("/", 2)[-2] for n in names), (part, names)
+    assert all(scopes.scope_of(n) == "attention_core" for n in names), names

@@ -26,45 +26,187 @@ def _tol(dtype):
 # ---------------------------------------------------------------------------
 
 FLASH_CASES = [
-    # b, sq, skv, kvh, G, dh, dv, window, cap
-    (2, 256, 256, 2, 2, 64, 64, None, None),
-    (2, 256, 256, 2, 2, 64, 64, 100, None),
-    (1, 300, 300, 1, 4, 32, 32, None, 50.0),
-    (1, 128, 128, 4, 1, 192, 128, None, None),     # MLA: dv != dh
-    (1, 512, 512, 1, 1, 128, 128, 64, 30.0),       # window + cap
-    (2, 64, 512, 2, 2, 64, 64, None, None),        # q is a suffix (prefill tail)
+    # b, s, kvh, G, dh, window, cap
+    (2, 512, 2, 1, 128, None, None),       # causal MHA
+    (1, 1024, 2, 1, 128, None, None),      # two 512-blocks: one skipped
+    (1, 512, 2, 2, 128, None, None),       # GQA: multi-query form per kv head
+    (1, 512, 1, 2, 128, 200, None),        # sliding window
+    (1, 512, 2, 1, 128, None, 30.0),       # logit soft-cap
+    (1, 512, 1, 2, 256, 256, 50.0),        # gemma2-like: dh 256, window, cap
 ]
+
+
+def _flash_inputs(case, dtype, rng):
+    b, s, kvh, G, dh, _, _ = case
+    q = jnp.asarray(rng.normal(0, 1, (b, s, kvh, G, dh)), dtype)
+    k = jnp.asarray(rng.normal(0, 1, (b, s, kvh, dh)), dtype)
+    v = jnp.asarray(rng.normal(0, 1, (b, s, kvh, dh)), dtype)
+    return q, k, v
+
+
+def _flash_rng():
+    """Each flash test draws from its own generator, so that adding one
+    leaves the module RNG's draws for the tests below unchanged."""
+    return np.random.default_rng(14)
 
 
 @pytest.mark.parametrize("case", FLASH_CASES)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention(case, dtype):
-    b, sq, skv, kvh, G, dh, dv, window, cap = case
-    q = jnp.asarray(RNG.normal(0, 1, (b, sq, kvh, G, dh)), dtype)
-    k = jnp.asarray(RNG.normal(0, 1, (b, skv, kvh, dh)), dtype)
-    v = jnp.asarray(RNG.normal(0, 1, (b, skv, kvh, dv)), dtype)
-    qp = jnp.arange(skv - sq, skv, dtype=jnp.int32)
-    kp = jnp.arange(skv, dtype=jnp.int32)
-    out = fk.flash_attention_pallas(q, k, v, qp, kp, window=window, cap=cap,
+    *_, window, cap = case
+    q, k, v = _flash_inputs(case, dtype, _flash_rng())
+    pos = jnp.arange(q.shape[1], dtype=jnp.int32)
+    out = fk.flash_attention_pallas(q, k, v, window=window, cap=cap,
                                     interpret=True)
-    ref = fr.attention(q, k, v, qp, kp, window=window, cap=cap)
+    ref = fr.attention(q, k, v, pos, pos, window=window, cap=cap)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("case", FLASH_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grad(case, dtype):
+    """The kernel's backward (dq and dk/dv kernels) == jax.grad of ref.py,
+    for a random cotangent."""
+    *_, window, cap = case
+    rng = _flash_rng()
+    q, k, v = _flash_inputs(case, dtype, rng)
+    pos = jnp.arange(q.shape[1], dtype=jnp.int32)
+    ct = jnp.asarray(rng.normal(0, 1, q.shape), jnp.float32)
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32) * ct)
+
+    got = jax.grad(loss(lambda q, k, v: fk.flash_attention_pallas(
+        q, k, v, window=window, cap=cap, interpret=True)), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: fr.attention(
+        q, k, v, pos, pos, window=window, cap=cap)), (0, 1, 2))(q, k, v)
+    for name, g, w in zip("qkv", got, want):
+        assert g.dtype == dtype, name
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32),
+                                   err_msg=f"d{name}", **_tol(dtype))
 
 
 def test_flash_matches_model_chunked_core():
     """kernel == ref == the model-side banded chunked core."""
     from repro.models.attention import attention_core
-    b, s, kvh, G, dh = 1, 1024, 2, 1, 64
-    q = jnp.asarray(RNG.normal(0, 1, (b, s, kvh, G, dh)), jnp.float32)
-    k = jnp.asarray(RNG.normal(0, 1, (b, s, kvh, dh)), jnp.float32)
-    v = jnp.asarray(RNG.normal(0, 1, (b, s, kvh, dh)), jnp.float32)
-    pos = jnp.arange(s, dtype=jnp.int32)
+    q, k, v = _flash_inputs((1, 1024, 2, 1, 128, None, None), jnp.float32,
+                            _flash_rng())
+    pos = jnp.arange(q.shape[1], dtype=jnp.int32)
     for window in (None, 128):
-        a = fk.flash_attention_pallas(q, k, v, pos, pos, window=window,
-                                      interpret=True)
+        a = fk.flash_attention_pallas(q, k, v, window=window, interpret=True)
         c = attention_core(q, k, v, pos, pos, window=window, chunk=256)
         np.testing.assert_allclose(np.asarray(a), np.asarray(c), atol=5e-5)
+
+
+def test_flash_refuses_shapes_it_does_not_take():
+    q = jnp.zeros((1, 300, 1, 1, 128), jnp.float32)
+    k = jnp.zeros((1, 300, 1, 128), jnp.float32)
+    with pytest.raises(ValueError, match="does not take"):
+        fk.flash_attention_pallas(q, k, k, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# attention-core dispatch: which calls take the flash kernel
+# ---------------------------------------------------------------------------
+
+_S = 512
+_ARANGE = np.arange(_S, dtype=np.int32)
+
+
+def _route_case(name):
+    """(q, k, v, q_pos, k_pos, traced positions) of one call shape."""
+    bf = jnp.bfloat16
+
+    def qkv(sq, skv, kvh, G, dh, dv):
+        return (jnp.zeros((1, sq, kvh, G, dh), bf),
+                jnp.zeros((1, skv, kvh, dh), bf),
+                jnp.zeros((1, skv, kvh, dv), bf))
+
+    if name == "train_mha":
+        return (*qkv(_S, _S, 2, 1, 128, 128), _ARANGE, _ARANGE, False)
+    if name == "train_gqa":
+        return (*qkv(_S, _S, 1, 4, 128, 128), _ARANGE, _ARANGE, False)
+    if name == "decode":            # one new token against a 512-slot cache
+        return (*qkv(1, _S, 2, 1, 128, 128), np.full((1, 1), 7, np.int32),
+                np.zeros((1, _S), np.int32), False)
+    if name == "seq_tp_shard":      # q is a shard, its positions traced
+        return (*qkv(_S, _S, 2, 1, 128, 128), _ARANGE, _ARANGE, True)
+    if name == "offset_positions":  # a prefill tail: 512..1023 against 512 keys
+        return (*qkv(_S, _S, 2, 1, 128, 128), _ARANGE + _S, _ARANGE + _S,
+                False)
+    if name == "mla_dims":          # DeepSeek MLA: qk 192, v 128
+        return (*qkv(_S, _S, 2, 1, 192, 128), _ARANGE, _ARANGE, False)
+    if name == "dh_64":
+        return (*qkv(_S, _S, 2, 1, 64, 64), _ARANGE, _ARANGE, False)
+    if name == "s_300":
+        a = np.arange(300, dtype=np.int32)
+        return (*qkv(300, 300, 2, 1, 128, 128), a, a, False)
+    raise KeyError(name)
+
+
+ROUTES = [("train_mha", "interpret", "kernel"),
+          ("train_gqa", "interpret", "kernel"),
+          ("train_mha", "off", "core"),
+          ("decode", "interpret", "core"),
+          ("seq_tp_shard", "interpret", "core"),
+          ("offset_positions", "interpret", "core"),
+          ("mla_dims", "interpret", "core"),
+          ("dh_64", "interpret", "core"),
+          ("s_300", "interpret", "core")]
+
+
+@pytest.mark.parametrize("name,mode,path", ROUTES)
+def test_attention_core_route(monkeypatch, name, mode, path):
+    """Train and prefill shapes take the kernel; decode, sequence shards,
+    positions not 0..s-1, MLA's dims, head dims and lengths the kernel
+    does not take, and Pallas off run the jnp core."""
+    from repro.models.attention import attention_core
+    monkeypatch.setenv("REPRO_PALLAS", mode)
+    q, k, v, qp, kp, traced = _route_case(name)
+    if traced:
+        jaxpr = jax.make_jaxpr(lambda q, k, v, qp: attention_core(
+            q, k, v, qp, kp))(q, k, v, qp)
+    else:
+        jaxpr = jax.make_jaxpr(lambda q, k, v: attention_core(
+            q, k, v, qp, kp))(q, k, v)
+    took = "kernel" if "pallas_call" in str(jaxpr) else "core"
+    assert took == path
+
+
+@pytest.mark.parametrize("dtype,rtol", [("float32", 1e-4), ("bfloat16", 3e-2)])
+def test_lm_loss_takes_the_kernel_and_matches_the_core(monkeypatch, dtype,
+                                                       rtol):
+    """A 2-layer OLMo-family model (dh 128, s 512): loss and gradients
+    through the interpreted kernel == through the jnp core, each gradient
+    within ``rtol`` of the core's by norm (bf16: a few roundings of 2^-8)."""
+    import dataclasses
+
+    from repro.configs import reduced_config
+    from repro.models import model as M
+    from repro.models.common import ShardCtx, instantiate_tree
+
+    cfg = dataclasses.replace(reduced_config("olmo-1b"), n_heads=2,
+                              n_kv_heads=2, d_head=128, d_model=256,
+                              dtype=dtype)
+    params = instantiate_tree(M.model_defs(cfg, 1), jax.random.key(0))
+    rng = np.random.default_rng(1)
+    ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 513)), jnp.int32)
+
+    def run(mode):
+        monkeypatch.setenv("REPRO_PALLAS", mode)
+        fn = jax.value_and_grad(lambda p: M.lm_loss(
+            cfg, ShardCtx(), p, ids[:, :-1], ids[:, 1:])[0])
+        return str(jax.make_jaxpr(fn)(params)), jax.jit(fn)(params)
+
+    jaxpr_k, (loss_k, g_k) = run("interpret")
+    jaxpr_c, (loss_c, g_c) = run("off")
+    assert "pallas_call" in jaxpr_k and "pallas_call" not in jaxpr_c
+    np.testing.assert_allclose(float(loss_k), float(loss_c), rtol=rtol / 10)
+    for a, b in zip(jax.tree.leaves(g_k), jax.tree.leaves(g_c)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= rtol * np.linalg.norm(b)
 
 
 # ---------------------------------------------------------------------------
