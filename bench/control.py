@@ -58,6 +58,7 @@ def judged(cell, readings: dict) -> dict:
 
 def train_control(cell, seed: int, require_tpu: bool = True) -> dict:
     from bench import run as R
+    from bench.reference import training
     from bench.train_cell import gaps
     devices, _ = R.device_check(cell.chips, require_tpu)
     tr = cell.traffic
@@ -78,9 +79,9 @@ def train_control(cell, seed: int, require_tpu: bool = True) -> dict:
 
     def train(policy=tr["policy"], lr=tr["lr"], batches=host,
               precision="f32"):
-        return ref.train(cell.config, policy, lr, key, batches,
-                         replicas=cell.chips, precision=precision,
-                         devices=devices[:cell.chips])
+        return training.train(ref, cell.config, policy, lr, key, batches,
+                              replicas=cell.chips, precision=precision,
+                              devices=devices[:cell.chips])
 
     lo = train()
     rec = {"seed": seed}
@@ -92,8 +93,8 @@ def train_control(cell, seed: int, require_tpu: bool = True) -> dict:
         rec[form] = judged(cell, {
             "loss_gap": float(np.max(step_gaps)),
             "step_loss_gaps": step_gaps.tolist(),
-            "grad_norm_gap": gaps(hi["grad"], lo["grad"], ref.flat),
-            "change_norm_gap": gaps(hi["change"], lo["change"], ref.flat,
+            "grad_norm_gap": gaps(hi["grad"], lo["grad"]),
+            "change_norm_gap": gaps(hi["change"], lo["change"],
                                     keep_from=lo["grad"]),
         })
     return rec

@@ -16,6 +16,10 @@ map from op name to ``op_name``, which the harness uses;
 :func:`op_names_from_hlo` builds the same map from a compiled program's HLO
 text, for checks without a chip.
 
+:data:`SCOPES` are the ``X`` of every ``step_ms.X`` per-layer metric of
+``BENCHMARK.json`` but ``unscoped`` (:func:`registered`), so that a metric
+added for a new scope of the program is attributed with no edit here.
+
 Attribution: an op goes to the innermost of :data:`SCOPES` named in its
 ``op_name`` path, after transform wrappers such as ``jvp(...)`` and
 ``transpose(...)`` are stripped (``jit(...)`` is a function's name, not a
@@ -37,6 +41,7 @@ Modules`` line.
 from __future__ import annotations
 
 import json
+import os
 import re
 from collections import Counter
 from dataclasses import dataclass
@@ -45,8 +50,9 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 from bench import devtrace
 from bench.devtrace import Op, Trace
 
-SCOPES = ("embed", "blocks", "attention", "attention_core", "mlp",
-          "lm_head", "optimizer", "sync", "step_metrics")
+MANIFEST = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "BENCHMARK.json")
+METRIC = "step_ms."
 UNSCOPED = "unscoped"
 MODULES_LINE = "XLA Modules"
 DISPATCH = "bench.step_dispatch"
@@ -61,6 +67,19 @@ _OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 # ---------------------------------------------------------------------------
 # the attribution rule
 # ---------------------------------------------------------------------------
+
+
+def registered(manifest: str = MANIFEST) -> Tuple[str, ...]:
+    """The scopes device time is attributed to, in the manifest's order:
+    the ``X`` of each per-layer metric ``step_ms.X`` but ``unscoped``."""
+    with open(manifest) as f:
+        metrics = [m["name"] for m in json.load(f)["per_layer"]]
+    return tuple(dict.fromkeys(
+        n[len(METRIC):] for n in metrics
+        if n.startswith(METRIC) and n != METRIC + UNSCOPED))
+
+
+SCOPES = registered()
 
 
 def _components(path: str) -> List[str]:

@@ -9,8 +9,10 @@ check needs on the way: each step's loss, the first gradient as Adam got it
 change after the last of these steps.  The window then runs the same call
 on the same state in a closed loop, reading each step's loss.  After the
 window the program's state is freed and the plain reference
-(``bench/reference/<reference>.py``) runs the checked steps from the same
-seed; the check compares, by the worst matrix of the worst replica:
+(``bench/reference/training.py`` training the model of
+``bench/reference/<reference>.py``) runs the checked steps from the same
+seed; the check compares, by the worst matrix of the worst replica, each
+side's matrices named by ``bench/names.py``'s rule:
 
 * ``loss_gap``: |program loss - reference loss| of each checked step;
 * ``grad_norm_gap``: |program norm - reference norm| of the first
@@ -26,48 +28,121 @@ import dataclasses
 import gc
 import math
 import time
+import typing
 
 import numpy as np
+
+from bench import names
 
 ADAM_B1 = 0.9
 
 
+def _dataclass_in(tp):
+    """The dataclass a field's declared type names (``Optional[X]`` too),
+    or None."""
+    if dataclasses.is_dataclass(tp):
+        return tp
+    return next((a for a in typing.get_args(tp)
+                 if dataclasses.is_dataclass(a)), None)
+
+
+def _field_value(tp, value, where: str):
+    sub = _dataclass_in(tp)
+    if sub is not None and isinstance(value, dict):
+        hints = typing.get_type_hints(sub)
+        unknown = sorted(set(value) - set(hints))
+        if unknown:
+            raise ValueError(f"{where}: {sub.__name__} has no field "
+                             f"{', '.join(map(repr, unknown))}")
+        return sub(**{k: _field_value(hints[k], v, f"{where}.{k}")
+                      for k, v in value.items()})
+    if isinstance(value, list):
+        return tuple(value)
+    return value
+
+
 def model_config(cfg: dict, name: str):
+    """The program's ``ModelConfig`` of a configuration file: every field
+    the file gives, each sub-config object (``moe``, ``mla``, ...) as the
+    dataclass its field declares, lists as tuples, and ``arch_type``
+    "dense" unless the file gives one.  A key inside a sub-config that its
+    dataclass lacks raises; top-level keys that are no field (``kind``,
+    ``reference``, ``published``, ``reduced``, ``notes``, ...) describe the
+    configuration and are left out."""
     from repro.configs.base import ModelConfig
-    fields = {f.name for f in dataclasses.fields(ModelConfig)}
-    kw = {k: v for k, v in cfg.items()
-          if k in fields and k not in ("name", "source")}
-    return ModelConfig(name=name, arch_type="dense", source=cfg["source"],
-                       **kw)
+    hints = typing.get_type_hints(ModelConfig)
+    kw = {"arch_type": "dense"}
+    for f in dataclasses.fields(ModelConfig):
+        if f.name != "name" and f.name in cfg:
+            kw[f.name] = _field_value(hints[f.name], cfg[f.name], f.name)
+    return ModelConfig(name=name, **kw)
+
+
+def _program_stacked(tree):
+    """``stacked`` of the program's parameter tree: a leaf under ``scan``
+    leads with its layer axis (the scan's steps); a routed expert's weight,
+    a leaf beside ``router`` other than the router, then with its expert
+    axis."""
+    import jax
+    paths = {names.keys_of(p)
+             for p, _ in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+    def stacked(keys):
+        expert = (keys[-1] != "router"
+                  and keys[:-1] + ("router",) in paths)
+        return int(keys[0] == "scan") + int(expert)
+    return stacked
 
 
 def program_norms(tree) -> dict:
-    """Per replica and per matrix (per layer for scanned block weights)
-    L2 norms of a params-shaped tree whose leaves lead with the replica
-    axis; named as the reference names them."""
-    import jax
-    import jax.numpy as jnp
-    out = {}
-    for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        keys = [getattr(k, "key", getattr(k, "idx", None)) for k in path]
-        name = str(keys[-1])
-        lead = 2 if keys[0] == "scan" else 1
-        out[name] = jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)),
-                                     axis=tuple(range(lead, x.ndim))))
-    return out
+    """Per leaf of a params-shaped tree whose leaves lead with the replica
+    axis, under its joined key path: the L2 norms of its matrices, per
+    replica, per layer of a scanned leaf and per expert of a routed weight
+    (traceable; :func:`program_matrices` names them)."""
+    return names.path_norms(tree, _program_stacked(tree))
 
 
-def gaps(prog: dict, ref: dict, flat, keep_from=None) -> float:
+def program_matrices(norms: dict) -> dict:
+    """:func:`program_norms` named as ``bench/names.py`` names matrices.
+
+    The program holds its blocks in ``prefix`` (a list of layers), ``scan``
+    (a list of the layers of one unit, each leaf stacked over the units)
+    and ``tail`` (a list of layers): with P prefix layers and U layers a
+    unit, ``scan/u`` at unit j is layer P + j * U + u, and ``tail/i``
+    follows the last unit."""
+    paths = [p.split("/") for p in norms]
+    prefix = len({p[1] for p in paths if p[0] == "prefix"})
+    unit = len({p[1] for p in paths if p[0] == "scan"})
+    units = max((np.asarray(norms[p]).shape[1] for p in norms
+                 if p.startswith("scan/")), default=0)
+
+    def name_of(path, index):
+        keys = path.split("/")
+        if keys[0] == "prefix":
+            layer = int(keys[1])
+        elif keys[0] == "scan":
+            layer = prefix + index[0] * unit + int(keys[1])
+            index = index[1:]
+        elif keys[0] == "tail":
+            layer = prefix + units * unit + int(keys[1])
+        else:
+            return names.join([path, *index])
+        return names.join(["layers", layer, *keys[2:], *index])
+    return names.named(norms, name_of)
+
+
+def gaps(prog: dict, ref: dict, keep_from=None) -> float:
     """Worst |program - reference| norm over max(reference norm, median
-    reference norm of the replica), over the matrices kept."""
-    names_p, p = flat(prog)
-    names_r, r = flat(ref)
+    reference norm of the replica), over the matrices kept; both sides
+    named alike (``bench/names.py``)."""
+    names_p, p = names.flat(prog)
+    names_r, r = names.flat(ref)
     if names_p != names_r:
         raise RuntimeError(f"matrix names differ: {names_p} vs {names_r}")
     med = np.median(r, axis=1, keepdims=True)
     rel = np.abs(p - r) / np.maximum(r, med)
     if keep_from is not None:
-        _, g = flat(keep_from)
+        _, g = names.flat(keep_from)
         rel = np.where(g >= 1e-3 * np.median(g, axis=1, keepdims=True),
                        rel, 0.0)
     return float(np.max(rel))
@@ -83,6 +158,7 @@ def run(run) -> bool:
     from repro.launch import steps as steps_lib
     from repro.launch.state import init_train_state
 
+    from bench.reference import training
     from bench.run import generator, log, memory_peak, reference, run_key
 
     cell, tr = run.cell, run.cell.traffic
@@ -125,13 +201,12 @@ def run(run) -> bool:
         state, m = step(state, pool[i])
         losses.append(float(m["loss"]))
         if i == 0:
-            grad1 = {k: np.asarray(v) / np.float32(1 - ADAM_B1)
-                     for k, v in norms(state.opt.mu).items()}
+            grad1 = {k: v / np.float32(1 - ADAM_B1) for k, v in
+                     program_matrices(norms(state.opt.mu)).items()}
     params0 = jax.jit(lambda k: init(k).params, out_shardings=p_sh)(key)
     diff = jax.jit(lambda a, b: program_norms(
         jax.tree.map(lambda x, y: x - y, a, b)))
-    change = {k: np.asarray(v) for k, v in diff(state.params,
-                                                params0).items()}
+    change = program_matrices(diff(state.params, params0))
     del params0
     jax.block_until_ready(state)
 
@@ -168,10 +243,10 @@ def run(run) -> bool:
 
     del state, m, pool
     gc.collect()
-    ref_mod = reference(cell)
     t_ref = time.perf_counter()
-    ref = ref_mod.train(cell.config, tr["policy"], tr["lr"], key,
-                        host[:checked], replicas=R, devices=run.devices)
+    ref = training.train(reference(cell), cell.config, tr["policy"],
+                         tr["lr"], key, host[:checked], replicas=R,
+                         devices=run.devices)
     ref_loss = ref["loss"].mean(axis=1)
     log(f"[{cell.name}] reference {checked} steps in "
         f"{time.perf_counter() - t_ref!r} s; losses {losses} vs "
@@ -179,9 +254,9 @@ def run(run) -> bool:
     run.checks += [
         ("loss_gap", float(np.max(np.abs(np.asarray(losses) - ref_loss))),
          cell.limits["loss_gap"]),
-        ("grad_norm_gap", gaps(grad1, ref["grad"], ref_mod.flat),
+        ("grad_norm_gap", gaps(grad1, ref["grad"]),
          cell.limits["grad_norm_gap"]),
-        ("change_norm_gap", gaps(change, ref["change"], ref_mod.flat,
+        ("change_norm_gap", gaps(change, ref["change"],
                                  keep_from=ref["grad"]),
          cell.limits["change_norm_gap"]),
     ]

@@ -256,3 +256,55 @@ def test_recorded_v5e_scoped_step(tmp_path, capsys):
     got = {m: R.reader(m)(run) for m in METRICS}
     assert sum(got.values()) == pytest.approx(st.leaf_ns / 1e6 / 3)
     assert "clock offset from host" in capsys.readouterr().err
+
+
+def test_registered_scopes_are_the_step_ms_metrics():
+    assert scopes.registered() == scopes.SCOPES == (
+        "embed", "blocks", "attention", "attention_core", "mlp", "lm_head",
+        "optimizer", "sync", "step_metrics")
+
+
+def test_a_new_step_ms_metric_registers_its_scope(tmp_path, small,
+                                                 monkeypatch):
+    import json
+    with open(scopes.MANIFEST) as f:
+        doc = json.load(f)
+    doc["per_layer"].append(dict(doc["per_layer"][-1],
+                                 name="step_ms.router"))
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(doc))
+    registered = scopes.registered(str(path))
+    assert registered == scopes.SCOPES + ("router",)
+    op = ("jit(local_step)/transpose(jvp(blocks))/while/body/closed_call/"
+          "checkpoint/mlp/router/dot_general")
+    assert scopes.scope_of(op) == "mlp"
+    monkeypatch.setattr(scopes, "SCOPES", registered)
+    assert scopes.scope_of(op) == "router"
+    trace, _, op_names = small
+    op_names = dict(op_names, **{"fusion.3": op})
+    st = scopes.scope_times(trace, trace.window(), op_names, 2)
+    # fusion.3 [200, 300) on device 0 leaves mlp for router
+    assert st.ns["router"] == 50 and st.ns["mlp"] == 0
+    assert sum(st.ns.values()) == st.leaf_ns == 330
+
+
+def test_recorded_v5e_step_ms_reads_as_it_did(tmp_path):
+    """Every ``step_ms.*`` on the recorded trace, as the readers gave it
+    when the scopes were a fixed list."""
+    tdir = _recorded(tmp_path)
+    trace = devtrace.load_xplane(devtrace.find_xplane(tdir))
+    run = fake_run(trace=trace, trace_window=trace.window(), steps=3,
+                   trace_dir=tdir)
+    run.devices = []
+    assert {m: R.reader(m)(run) for m in METRICS} == {
+        "step_ms.embed": 0.017493333333333333,
+        "step_ms.blocks": 0.015094000000000002,
+        "step_ms.attention": 0.023314666666666668,
+        "step_ms.attention_core": 0.41307133333333335,
+        "step_ms.mlp": 0.022485333333333333,
+        "step_ms.lm_head": 0.022993,
+        "step_ms.optimizer": 0.0,
+        "step_ms.sync": 0.011288,
+        "step_ms.step_metrics": 0.0,
+        "step_ms.unscoped": 0.006268333333333333,
+    }
