@@ -53,7 +53,8 @@ COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
 
 class SetupError(RuntimeError):
     """The run cannot start: no accelerator, too few chips, an unknown
-    device kind, or a cell whose files are missing."""
+    device kind, a cell whose files are missing, or a counter that a
+    reader declares and the step does not return."""
 
 
 def log(*parts) -> None:
@@ -131,10 +132,24 @@ def reference(cell: Cell):
                        "bench_reference_" + cell.config["reference"])
 
 
+def reader_module(metric: str):
+    """A per-layer metric's reader module: ``read(run)``, and optionally
+    ``COUNTERS``, the names of scalars of the train step's metrics that it
+    reads per window step from ``run.data["counters"]``."""
+    return load_module(os.path.join(BENCH, "metrics", metric + ".py"),
+                       "bench_metric_" + metric.replace(".", "_"))
+
+
 def reader(metric: str) -> Callable:
-    mod = load_module(os.path.join(BENCH, "metrics", metric + ".py"),
-                      "bench_metric_" + metric.replace(".", "_"))
-    return mod.read
+    return reader_module(metric).read
+
+
+def step_counters(cell: Cell) -> Tuple[str, ...]:
+    """The union of the ``COUNTERS`` that the cell's per-layer readers
+    declare, in the manifest's order."""
+    return tuple(dict.fromkeys(
+        c for m in cell.per_layer
+        for c in getattr(reader_module(m["name"]), "COUNTERS", ())))
 
 
 # ---------------------------------------------------------------------------

@@ -18,7 +18,9 @@ text, for checks without a chip.
 
 :data:`SCOPES` are the ``X`` of every ``step_ms.X`` per-layer metric of
 ``BENCHMARK.json`` but ``unscoped`` (:func:`registered`), so that a metric
-added for a new scope of the program is attributed with no edit here.
+added for a new scope of the program is attributed with no edit here: a new
+scope takes the program's ``jax.named_scope``, a reader
+``bench/metrics/step_ms.<X>.py`` and its entry in the manifest.
 
 Attribution: an op goes to the innermost of :data:`SCOPES` named in its
 ``op_name`` path, after transform wrappers such as ``jvp(...)`` and
@@ -29,6 +31,14 @@ scope, and is never stripped).  Where a fusion lists several ``;``-joined
 counted apart as ``unmapped``.  Times are those of leaf ops
 (``devtrace.leaves``) clipped to the window, so the scopes' times add up to
 the leaf-op time.
+
+Nesting: a registered scope that the program opens inside another (a
+``router`` inside ``mlp``: ``.../mlp/router/dot_general``) takes the ops
+under it, so the outer scope's ``step_ms`` becomes its remainder, the ops
+under it and under none of its registered inner scopes.  Adding a scope
+thus splits its outer scope's time and leaves the total as it was; a name
+the manifest does not register is no scope, and its ops stay with the
+registered scope around it.
 
 Clock bracket: the step's program cannot start on the device before the
 host's ``bench.step_dispatch`` span that launched it starts, and the host's

@@ -7,7 +7,10 @@ compiles the step, and drives that same step and state through the first
 check needs on the way: each step's loss, the first gradient as Adam got it
 (its first moment after one step, over 1 - beta1), and the parameters'
 change after the last of these steps.  The window then runs the same call
-on the same state in a closed loop, reading each step's loss.  After the
+on the same state in a closed loop, reading each step's loss and, in the
+same fetch, the step counters that the cell's per-layer readers declare
+(their ``COUNTERS``: scalars of the step's metrics), which it keeps per
+step in ``run.data["counters"]``.  After the
 window the program's state is freed and the plain reference
 (``bench/reference/training.py`` training the model of
 ``bench/reference/<reference>.py``) runs the checked steps from the same
@@ -159,7 +162,8 @@ def run(run) -> bool:
     from repro.launch.state import init_train_state
 
     from bench.reference import training
-    from bench.run import generator, log, memory_peak, reference, run_key
+    from bench.run import (SetupError, generator, log, memory_peak,
+                           reference, run_key, step_counters)
 
     cell, tr = run.cell, run.cell.traffic
     R = cell.chips
@@ -193,6 +197,7 @@ def run(run) -> bool:
     pool = [{"ids": jax.device_put(b[:, :-1], in_sh),
              "labels": jax.device_put(b[:, 1:], in_sh)} for b in host]
     checked = int(tr["checked_steps"])
+    counted = step_counters(cell)
 
     norms = jax.jit(program_norms)
     losses = []
@@ -203,6 +208,13 @@ def run(run) -> bool:
         if i == 0:
             grad1 = {k: v / np.float32(1 - ADAM_B1) for k, v in
                      program_matrices(norms(state.opt.mu)).items()}
+            missing = [c for c in counted
+                       if c not in m or np.shape(m[c]) != ()]
+            if missing:
+                raise SetupError(
+                    f"{cell.name}: the train step returns no scalar "
+                    f"{', '.join(map(repr, missing))} that a reader "
+                    f"declares in COUNTERS")
     params0 = jax.jit(lambda k: init(k).params, out_shardings=p_sh)(key)
     diff = jax.jit(lambda a, b: program_norms(
         jax.tree.map(lambda x, y: x - y, a, b)))
@@ -213,6 +225,8 @@ def run(run) -> bool:
     tokens_per_step = B * tr["seq_len"]
     n = bad = 0
     i = checked
+    fetched = ("loss",) + counted
+    per_step = {c: [] for c in counted}
     gc.collect()
     with run.profiled():
         run.mark_setup_done()
@@ -222,7 +236,13 @@ def run(run) -> bool:
                 with run.span("bench.step_dispatch"):
                     state, m = step(state, pool[i % len(pool)])
                 with run.span("bench.read_loss"):
-                    loss = float(m["loss"])
+                    if counted:
+                        got = jax.device_get({k: m[k] for k in fetched})
+                        for c in counted:
+                            per_step[c].append(float(got[c]))
+                        loss = float(got["loss"])
+                    else:
+                        loss = float(m["loss"])
                 n += 1
                 i += 1
                 bad += not math.isfinite(loss)
@@ -234,6 +254,8 @@ def run(run) -> bool:
     run.end_to_end["tokens_per_s"] = n * tokens_per_step / run.window_s
     run.data.update(steps=n, tokens_per_step=tokens_per_step,
                     seq_len=tr["seq_len"], model=cell.config)
+    if counted:
+        run.data["counters"] = per_step
     run.memory_peak_bytes = memory_peak(run.devices)
     log(f"[{cell.name}] setup_s={run.setup_s!r} steps={n} "
         f"window_s={run.window_s!r} tokens_per_s="

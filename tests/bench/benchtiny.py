@@ -1,13 +1,14 @@
 """Shared helpers of the benchmark tests: a cell shrunk to a size a CPU
 test can hold, run through the harness with its look for a chip skipped.
 
-The shrunk train cell keeps the OLMo structure (attention, SwiGLU, tied
-embedding, LayerNorm, RoPE) at toy widths; the shrunk PS cell keeps 128
-topics on a 600-word vocabulary.  Their limits are set for these sizes
-from the readings of sound CPU runs (loss gap 6.5e-4 to 1.2e-3, gradient
-and change gaps under 0.4%) and of the float8 control (loss gap 0.016 to
-0.026, gradient gap 2.1% to 3.2%): the cells' own limits are for their
-full sizes on the chip.
+The shrunk train cell keeps its configuration's structure (for OLMo:
+attention, SwiGLU, tied embedding, LayerNorm, RoPE) at toy widths, its
+latent attention and experts too where the file has them; the shrunk PS
+cell keeps 128 topics on a 600-word vocabulary.  Their limits are set for
+these sizes from the readings of sound CPU runs (loss gap 6.5e-4 to
+1.2e-3, gradient and change gaps under 0.4%) and of the float8 control
+(loss gap 0.016 to 0.026, gradient gap 2.1% to 3.2%): the cells' own
+limits are for their full sizes on the chip.
 """
 import os
 import subprocess
@@ -22,6 +23,14 @@ for p in (ROOT, SRC):
 
 TINY_MODEL = dict(n_layers=2, d_model=64, n_heads=4, n_kv_heads=4,
                   d_head=16, d_ff=128, vocab_size=256)
+# the sub-configs' widths at that size, each set only where the file's
+# group has the key: a 32-wide kv latent, q/k heads of 16 + 8 rotary, v
+# heads of 16; 8 routed experts, 2 a token, 32 wide, shared experts of 64
+# together, leading dense layers of 96
+TINY_MLA = dict(kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                v_head_dim=16)
+TINY_MOE = dict(n_experts=8, top_k=2, d_expert=32, d_shared=64,
+                d_ff_dense=96)
 TINY_TRAIN_LIMITS = {"loss_gap": 0.005, "grad_norm_gap": 0.01,
                      "change_norm_gap": 0.004}
 
@@ -55,11 +64,32 @@ def held_cell(name: str):
                   [])
 
 
+def tiny_config(config: dict) -> dict:
+    """A train configuration file at :data:`TINY_MODEL`'s size.  Its
+    ``mla`` and ``moe`` groups, where it has them, go to :data:`TINY_MLA`
+    and :data:`TINY_MOE` widths; the experts keep ``first_dense_layers``,
+    with two expert layers after them, and ``experts_held`` its share of
+    ``n_experts``."""
+    out = {**config, **TINY_MODEL}
+    for key, tiny in (("mla", TINY_MLA), ("moe", TINY_MOE)):
+        if key in config:
+            out[key] = {**config[key], **{k: v for k, v in tiny.items()
+                                          if k in config[key]}}
+    moe = config.get("moe")
+    if moe is not None:
+        out["n_layers"] = moe.get("first_dense_layers", 0) + 2
+        if "experts_held" in moe:
+            out["moe"]["experts_held"] = max(1, round(
+                TINY_MOE["n_experts"] * moe["experts_held"]
+                / moe["n_experts"]))
+    return out
+
+
 def tiny_cell(name: str):
     from bench import run as R
     cell = held_cell(name) if name in HELD else R.find_cell(name)
     if cell.kind == "train":
-        cell.config.update(TINY_MODEL)
+        cell.config = tiny_config(cell.config)
         cell.traffic.update(seq_len=32, pool_batches=6)
         cell.limits = dict(TINY_TRAIN_LIMITS)
     else:

@@ -3,7 +3,7 @@ its trace for ``bench/testdata``.
 
     python tests/bench/record_scoped_step.py --out bench/testdata
 
-compiles the shrunk ``olmo-1b-4l.cvap3`` step (``benchtiny.TINY_MODEL``, 2
+compiles the shrunk ``olmo-1b-4l.cvap3`` step (``benchtiny.tiny_cell``, 2
 rows of 1,024 tokens, so that attention runs its per-chunk map), writes
 the compiled HLO text (``tiny_scoped_step.hlo.txt.gz``), then profiles
 three steps inside the harness's ``bench.window``, ``bench.step_dispatch``
@@ -30,9 +30,10 @@ HLO_FILE = "tiny_scoped_step.hlo.txt.gz"
 XPLANE_FILE = "tiny_scoped_step.xplane.pb.gz"
 
 
-def tiny_step(seq_len: int = SEQ_LEN, batch: int = BATCH):
+def tiny_step(seq_len: int = SEQ_LEN, batch: int = BATCH,
+              cell: str = "olmo-1b-4l.cvap3"):
     """(jitted step, init(key) -> state, batches(n) -> list of batches)
-    of the shrunk train cell."""
+    of the train cell ``cell`` shrunk by ``benchtiny.tiny_cell``."""
     import jax
     import numpy as np
 
@@ -42,9 +43,9 @@ def tiny_step(seq_len: int = SEQ_LEN, batch: int = BATCH):
     from repro.launch import steps as steps_lib
     from repro.launch.state import init_train_state
 
-    cell = tiny_cell("olmo-1b-4l.cvap3")
-    tr = cell.traffic
-    cfg = model_config(cell.config, "olmo-tiny")
+    shrunk = tiny_cell(cell)
+    tr = shrunk.traffic
+    cfg = model_config(shrunk.config, cell + "-tiny")
     tcfg = TrainConfig(arch=cfg.name, steps=1, lr=tr["lr"],
                        warmup_steps=tr["warmup_steps"],
                        optimizer=tr["optimizer"], log_every=1,

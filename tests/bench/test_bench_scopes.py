@@ -18,6 +18,8 @@ dispatch spans start at 1, 11, 21 and the loss reads end at 10.2, 20.1,
 clock and are recorded 1 ms earlier, as a device clock 1 ms behind.
 """
 import gzip
+import itertools
+import json
 import os
 import sys
 
@@ -33,6 +35,35 @@ from bench import run as R  # noqa: E402
 TESTDATA = os.path.join(ROOT, "bench", "testdata")
 MS = 1_000_000
 METRICS = ["step_ms." + s for s in scopes.SCOPES + (scopes.UNSCOPED,)]
+# the scopes of OLMo's train step, which the fixtures' op names use; a
+# manifest may register more
+OLMO_SCOPES = ("embed", "blocks", "attention", "attention_core", "mlp",
+               "lm_head", "optimizer", "sync", "step_metrics")
+
+
+def step_ms_entries(manifest: str):
+    """The ``X`` of the manifest's ``step_ms.X`` entries, ``unscoped``
+    left out, in order."""
+    with open(manifest) as f:
+        names = [m["name"] for m in json.load(f)["per_layer"]]
+    return tuple(n[len(scopes.METRIC):] for n in names
+                 if n.startswith(scopes.METRIC)
+                 and n != scopes.METRIC + scopes.UNSCOPED)
+
+
+def manifest_with_new_scope(tmp_path):
+    """A copy of ``BENCHMARK.json`` with one more ``step_ms.X`` entry, X a
+    name the manifest does not register yet; (its path, X)."""
+    with open(scopes.MANIFEST) as f:
+        doc = json.load(f)
+    new = next(n for n in (f"probe{i}" for i in itertools.count())
+               if n not in step_ms_entries(scopes.MANIFEST))
+    entry = next(m for m in doc["per_layer"]
+                 if m["name"].startswith(scopes.METRIC))
+    doc["per_layer"].append(dict(entry, name=scopes.METRIC + new))
+    path = tmp_path / "BENCHMARK.json"
+    path.write_text(json.dumps(doc))
+    return str(path), new
 
 
 @pytest.mark.parametrize("op_name, scope", [
@@ -106,10 +137,11 @@ def test_scope_times_by_hand(small):
     # device 0: attention_core 90 (clipped), attention 100, mlp 100,
     # copy.1 50 + unmapped fusion.6 20 (clipped), optimizer 50, sync 30;
     # device 1: attention_core 90, lm_head 60, embed 40, step_metrics 30
-    assert st.ns == {"embed": 20, "blocks": 0, "attention": 50,
-                     "attention_core": 90, "mlp": 50, "lm_head": 30,
-                     "optimizer": 25, "sync": 15, "step_metrics": 15,
-                     "unscoped": 35}
+    want = {"embed": 20, "blocks": 0, "attention": 50,
+            "attention_core": 90, "mlp": 50, "lm_head": 30,
+            "optimizer": 25, "sync": 15, "step_metrics": 15,
+            "unscoped": 35}
+    assert st.ns == {**dict.fromkeys(st.ns, 0), **want}
     assert st.unmapped_ns == 10
     assert st.leaf_ns == 330
     assert sum(st.ns.values()) == st.leaf_ns
@@ -258,34 +290,71 @@ def test_recorded_v5e_scoped_step(tmp_path, capsys):
     assert "clock offset from host" in capsys.readouterr().err
 
 
-def test_registered_scopes_are_the_step_ms_metrics():
-    assert scopes.registered() == scopes.SCOPES == (
-        "embed", "blocks", "attention", "attention_core", "mlp", "lm_head",
-        "optimizer", "sync", "step_metrics")
+@pytest.mark.parametrize("manifest", ["committed", "one_more_scope"])
+def test_registered_scopes_are_the_step_ms_metrics(manifest, tmp_path):
+    if manifest == "committed":
+        path = scopes.MANIFEST
+        assert scopes.registered() == scopes.SCOPES
+    else:
+        path, new = manifest_with_new_scope(tmp_path)
+        assert scopes.registered(path)[-1] == new
+    assert scopes.registered(path) == step_ms_entries(path)
+    assert set(OLMO_SCOPES) <= set(scopes.registered(path))
+    assert scopes.UNSCOPED not in scopes.registered(path)
 
 
 def test_a_new_step_ms_metric_registers_its_scope(tmp_path, small,
                                                  monkeypatch):
-    import json
-    with open(scopes.MANIFEST) as f:
-        doc = json.load(f)
-    doc["per_layer"].append(dict(doc["per_layer"][-1],
-                                 name="step_ms.router"))
-    path = tmp_path / "BENCHMARK.json"
-    path.write_text(json.dumps(doc))
-    registered = scopes.registered(str(path))
-    assert registered == scopes.SCOPES + ("router",)
+    path, new = manifest_with_new_scope(tmp_path)
+    registered = scopes.registered(path)
+    assert registered == scopes.SCOPES + (new,)
     op = ("jit(local_step)/transpose(jvp(blocks))/while/body/closed_call/"
-          "checkpoint/mlp/router/dot_general")
+          f"checkpoint/mlp/{new}/dot_general")
     assert scopes.scope_of(op) == "mlp"
     monkeypatch.setattr(scopes, "SCOPES", registered)
-    assert scopes.scope_of(op) == "router"
+    assert scopes.scope_of(op) == new
     trace, _, op_names = small
     op_names = dict(op_names, **{"fusion.3": op})
     st = scopes.scope_times(trace, trace.window(), op_names, 2)
-    # fusion.3 [200, 300) on device 0 leaves mlp for router
-    assert st.ns["router"] == 50 and st.ns["mlp"] == 0
+    # fusion.3 [200, 300) on device 0 leaves mlp for the new scope
+    assert st.ns[new] == 50 and st.ns["mlp"] == 0
     assert sum(st.ns.values()) == st.leaf_ns == 330
+
+
+def test_a_nested_scope_splits_its_outer_scope_on_the_recorded_trace(
+        tmp_path, monkeypatch):
+    """A probe scope registered by a temporary manifest and planted inside
+    ``mlp`` in the op names of the recorded v5e trace: the readers of the
+    other scopes read as before, the probe takes what it holds from
+    ``mlp``, and the scopes still add up to the leaf-op time."""
+    tdir = _recorded(tmp_path / "trace")
+    path = devtrace.find_xplane(tdir)
+    trace = devtrace.load_xplane(path)
+    names = scopes.op_names_from_xplane(path)
+    manifest, new = manifest_with_new_scope(tmp_path)
+    run = fake_run(trace=trace, trace_window=trace.window(), steps=3,
+                   trace_dir=tdir)
+    run.devices = []
+    before = scopes.of_run(run)
+    # every other op named under mlp moves into the probe
+    under = sorted(k for k, v in names.items()
+                   if scopes.scope_of(v) == "mlp"
+                   and "/mlp/" in v.split(";", 1)[0])[::2]
+    nested = {k: (v.replace("/mlp/", f"/mlp/{new}/", 1) if k in under
+                  else v) for k, v in names.items()}
+    assert under and all(f"/mlp/{new}/" in nested[k] for k in under)
+    monkeypatch.setattr(scopes, "SCOPES", scopes.registered(manifest))
+    monkeypatch.setattr(scopes, "op_names_from_xplane", lambda p: nested)
+    run.data.pop("step_scopes")
+    after = scopes.of_run(run)
+    assert after.ns[new] > 0
+    assert after.ns["mlp"] + after.ns[new] == pytest.approx(
+        before.ns["mlp"])
+    for s in before.ns:
+        if s != "mlp":
+            assert after.ns[s] == before.ns[s], s
+    assert sum(after.ns.values()) == pytest.approx(after.leaf_ns)
+    assert after.leaf_ns == before.leaf_ns
 
 
 def test_recorded_v5e_step_ms_reads_as_it_did(tmp_path):
@@ -296,7 +365,8 @@ def test_recorded_v5e_step_ms_reads_as_it_did(tmp_path):
     run = fake_run(trace=trace, trace_window=trace.window(), steps=3,
                    trace_dir=tdir)
     run.devices = []
-    assert {m: R.reader(m)(run) for m in METRICS} == {
+    got = {m: R.reader(m)(run) for m in METRICS}
+    want = {
         "step_ms.embed": 0.017493333333333333,
         "step_ms.blocks": 0.015094000000000002,
         "step_ms.attention": 0.023314666666666668,
@@ -308,3 +378,5 @@ def test_recorded_v5e_step_ms_reads_as_it_did(tmp_path):
         "step_ms.step_metrics": 0.0,
         "step_ms.unscoped": 0.006268333333333333,
     }
+    # a scope registered later reads nothing on this OLMo step
+    assert got == {**dict.fromkeys(got, 0.0), **want}

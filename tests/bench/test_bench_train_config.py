@@ -171,3 +171,82 @@ def test_two_matrices_with_one_name_raise():
     with pytest.raises(ValueError, match="two matrices named 'w'"):
         names.named({"a": np.ones((1, 2)), "b": np.ones((1,))},
                     lambda path, index: "w")
+
+
+# the latent attention and experts of DeepSeek-V2-Lite's config.json at
+# their published widths, 8 of the 64 routed experts held
+PUBLISHED_MLA_MOE = {
+    **TINY_MLA_MOE, "n_layers": 5, "d_model": 2048, "n_heads": 16,
+    "n_kv_heads": 16, "d_head": 192, "vocab_size": 12800,
+    "mla": {"kv_lora_rank": 512, "qk_nope_head_dim": 128,
+            "qk_rope_head_dim": 64, "v_head_dim": 128},
+    "moe": {"n_experts": 64, "experts_held": 8, "top_k": 6,
+            "d_expert": 1408, "n_shared_experts": 2, "d_shared": 2816,
+            "first_dense_layers": 1, "d_ff_dense": 10944},
+}
+
+
+def test_tiny_config_shrinks_the_sub_configs_it_has():
+    from benchtiny import TINY_MLA, TINY_MODEL, tiny_config
+    tiny = tiny_config(PUBLISHED_MLA_MOE)
+    assert tiny["mla"] == TINY_MLA
+    assert tiny["moe"] == {"n_experts": 8, "experts_held": 1, "top_k": 2,
+                           "d_expert": 32, "n_shared_experts": 2,
+                           "d_shared": 64, "first_dense_layers": 1,
+                           "d_ff_dense": 96}
+    assert tiny["n_layers"] == 3
+    assert {k: tiny[k] for k in TINY_MODEL if k != "n_layers"} == {
+        k: v for k, v in TINY_MODEL.items() if k != "n_layers"}
+    # only the keys a group has are set; the file is left as it was
+    bare = {**PUBLISHED_MLA_MOE, "moe": {"n_experts": 64, "top_k": 6,
+                                         "d_expert": 1408}}
+    del bare["mla"]
+    tiny = tiny_config(bare)
+    assert tiny["moe"] == {"n_experts": 8, "top_k": 2, "d_expert": 32}
+    assert "mla" not in tiny and tiny["n_layers"] == 2
+    assert PUBLISHED_MLA_MOE["moe"]["n_experts"] == 64
+
+
+def test_tiny_cell_of_latent_attention_and_experts_compiles(monkeypatch):
+    """``tiny_cell`` and ``tiny_step`` on a cell whose configuration has
+    ``mla`` and ``moe`` at published widths: the program's sub-configs come
+    out at the tiny widths, and the tiny step compiles with the expert
+    layers under the ``mlp`` scope."""
+    import jax
+
+    from bench import run as R
+    from bench import scopes
+    from bench.train_cell import model_config
+    from benchtiny import TINY_MLA, tiny_cell
+    from record_scoped_step import tiny_step
+    from repro.configs.base import MLAConfig, MoEConfig
+
+    name = "tiny-mla-moe.cvap3"
+    program = {**PUBLISHED_MLA_MOE,
+               "moe": {k: v for k, v in PUBLISHED_MLA_MOE["moe"].items()
+                       if k != "experts_held"}}
+    find_cell = R.find_cell
+
+    def find(cell):
+        if cell != name:
+            return find_cell(cell)
+        olmo = find_cell("olmo-1b-4l.cvap3")
+        return R.Cell(name, 1, json.loads(json.dumps(program)),
+                      dict(olmo.traffic), {}, olmo.end_to_end, [])
+    monkeypatch.setattr(R, "find_cell", find)
+    cfg = model_config(tiny_cell(name).config, "tiny")
+    assert cfg.mla == MLAConfig(**TINY_MLA)
+    assert cfg.moe == MoEConfig(n_experts=8, top_k=2, d_expert=32,
+                                n_shared_experts=2, d_shared=64,
+                                first_dense_layers=1, d_ff_dense=96)
+    assert (cfg.n_layers, cfg.d_model, cfg.vocab_size) == (3, 64, 256)
+    step, init, batches = tiny_step(seq_len=128, batch=1, cell=name)
+    state = jax.eval_shape(init, jax.random.key(0))
+    batch = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                         batches(1)[0])
+    op_names = scopes.op_names_from_hlo(step.lower(state, batch).compile()
+                                        .as_text())
+    assert any("/mlp/" in v and "transpose(" in v
+               for v in op_names.values())
+    assert {"embed", "attention", "attention_core", "mlp", "lm_head"} <= {
+        scopes.scope_of(v) for v in op_names.values()}
