@@ -7,7 +7,8 @@ walks only the key blocks its mask reaches: blocks wholly above the
 diagonal (or wholly left of a sliding window) are neither fetched nor
 computed, in the forward and in both backward kernels (dq, and dk/dv).  No
 score or probability block is written to HBM; the forward saves the
-per-row logsumexp for the backward.
+per-row logsumexp for the backward, and names it and its output
+``ops.RESIDUALS`` for a layer checkpoint that keeps them.
 
 Precision: q·kᵀ takes q and k in their own dtype (bf16 in the model) with
 f32 accumulation; the softmax statistics are f32; the backward's products
@@ -29,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.pallas.ops.tpu.splash_attention import (
     splash_attention_kernel as splash, splash_attention_mask as masks)
+
+from repro.kernels.flash_attention.ops import RESIDUALS
 
 BLOCKS = (512, 256, 128)
 
@@ -58,7 +61,7 @@ def _splash(s: int, heads: int, mqa: bool, window: Optional[int],
     with jax.ensure_compile_time_eval():
         return make(masks.MultiHeadMask([one] * heads), block_sizes=sizes,
                     attn_logits_soft_cap=cap, head_shards=1, q_seq_shards=1,
-                    interpret=interpret)
+                    residual_checkpoint_name=RESIDUALS, interpret=interpret)
 
 
 def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

@@ -12,12 +12,26 @@ import numpy as np
 from repro.kernels import pallas_mode
 from repro.kernels.flash_attention import ref
 
+# The checkpoint name of the kernel's forward residuals (its output and the
+# per-row logsumexp): a layer checkpoint whose policy saves this name keeps
+# them, so the backward does not run the forward kernel again.
+RESIDUALS = "flash_residuals"
+
 
 def _is_arange(pos, n: int) -> bool:
     """``pos`` is known while tracing, and is 0..n-1."""
     if isinstance(pos, jax.core.Tracer) or np.shape(pos) != (n,):
         return False
     return bool(np.array_equal(np.asarray(pos), np.arange(n)))
+
+
+def kernel_runs(s: int, dh: int, dv: int) -> bool:
+    """Whether Pallas is on (or interpreted) and the kernel takes causal
+    self-attention over ``s`` positions with these head dims."""
+    if pallas_mode() not in ("on", "interpret"):
+        return False
+    from repro.kernels.flash_attention import kernel
+    return kernel.takes(s, dh, dv)
 
 
 def kernel_takes(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -28,11 +42,8 @@ def kernel_takes(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     and the kernel takes the head dims.  Decode (a cache is read, sq == 1),
     a sequence shard of q against gathered keys, and MLA's head dims are
     left to the caller's jnp core."""
-    if pallas_mode() not in ("on", "interpret"):
-        return False
-    from repro.kernels.flash_attention import kernel
-    s, dh = q.shape[1], q.shape[-1]
-    return (k.shape[1] == s and kernel.takes(s, dh, v.shape[-1])
+    s = q.shape[1]
+    return (k.shape[1] == s and kernel_runs(s, q.shape[-1], v.shape[-1])
             and _is_arange(q_pos, s) and _is_arange(k_pos, s))
 
 

@@ -10,6 +10,12 @@ Each layer of the train step runs under one ``jax.named_scope`` (``embed``,
 compiled op's ``op_name`` names its layer.  Scopes only label: the compiled
 step is the same program with or without them.
 
+The layer checkpoint's plan (``remat_plan``) is chosen while the step is
+traced, from the local batch shape, the train state's bytes and the memory
+the device reports: where they fit, the blocks' matmul outputs and the
+flash kernel's residuals are kept for the backward instead of computed
+again, and the step reports the bytes kept as ``remat_saved_bytes``.
+
 Gradients of model-axis-replicated leaves (routers, norm scales, seq-TP
 projections) are psum'd over the model axis so replicated copies stay
 bitwise identical (Megatron rule); model-sharded leaves' grads are already
@@ -18,7 +24,8 @@ complete.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional
+import math
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +66,37 @@ def _replicated_leaf_mask(cfg: ModelConfig, tp: int) -> PyTree:
 # ---------------------------------------------------------------------------
 
 
+def remat_plan(cfg: ModelConfig, ctx: ShardCtx, batch_shape: Tuple[int, int],
+               n_params: int, state_bytes: int, bytes_limit: Optional[int],
+               ) -> Tuple[Any, int]:
+    """The layer checkpoint's policy for a train step on one device, and the
+    bytes it keeps: ``(M.SAVE_MATMULS, bytes)`` when what it saves over the
+    local batch ``batch_shape`` (the layers under the checkpoint, as the
+    config groups them) fits in half of what the device holds beyond the
+    train state and one f32 copy of the ``n_params`` parameters (the
+    gradients); else ``(None, 0)``, full recompute.  A device that reports
+    no ``bytes_limit`` gets full recompute."""
+    if bytes_limit is None:
+        return None, 0
+    saved = M.saved_bytes(cfg, ctx, *batch_shape)
+    free = bytes_limit - state_bytes - 4 * n_params
+    if 0 < saved <= free / 2:
+        return M.SAVE_MATMULS, saved
+    return None, 0
+
+
+def device_bytes_limit(mesh) -> Optional[int]:
+    """The memory one device of the step holds, as it reports it (None
+    where it reports none, as the CPU does)."""
+    dev = jax.devices()[0] if mesh is None else mesh.devices.flat[0]
+    return (dev.memory_stats() or {}).get("bytes_limit")
+
+
+def _nbytes(tree) -> int:
+    return sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree.leaves(tree))
+
+
 def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
                     donate: bool = True, unroll: bool = False):
     """Returns jitted (state, batch) -> (state, metrics)."""
@@ -69,14 +107,20 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
     rep_mask = _replicated_leaf_mask(cfg, ctx.tp)
     all_axes = tuple(ctx.dp_axes) + ((ctx.model_axis,) if ctx.model_axis else ())
     pod_axis = "pod" if (mesh is not None and "pod" in mesh.axis_names) else None
+    bytes_limit = device_bytes_limit(mesh)
 
     def local_step(state: TrainState, batch: Dict):
         st = squeeze_dp(state)
+        keep, saved = (remat_plan(
+            cfg, ctx, batch["ids"].shape,
+            sum(math.prod(x.shape) for x in jax.tree.leaves(st.params)),
+            _nbytes(st), bytes_limit) if tcfg.remat else (None, 0))
 
         def loss_fn(p):
             return M.lm_loss(cfg, ctx, p, batch["ids"], batch["labels"],
                              extra_emb=batch.get("extra_emb"),
-                             remat=tcfg.remat, unroll=unroll)
+                             remat=tcfg.remat, remat_policy=keep,
+                             unroll=unroll)
 
         (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(st.params)
         if ctx.model_axis is not None:
@@ -113,6 +157,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
                     jnp.vdot(g, g).real for g in jax.tree.leaves(grads)
                 )).astype(jnp.float32),
                 "lr": lr,
+                "remat_saved_bytes": jnp.float32(saved),
             }
             if all_axes:
                 out_metrics = jax.tree.map(
@@ -129,7 +174,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
     if cfg.frontend is not None:
         batch_spec["extra_emb"] = P(bdp, None, None)
     metrics_spec = {k: P() for k in ("loss", "xent", "aux", "synced",
-                                     "grad_norm", "lr")}
+                                     "grad_norm", "lr", "remat_saved_bytes")}
     f = jax.shard_map(local_step, mesh=mesh,
                       in_specs=(state_spec, batch_spec),
                       out_specs=(state_spec, metrics_spec), check_vma=False)

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig
 from repro.kernels.flash_attention import ops as fa_ops
@@ -34,6 +35,7 @@ from repro.models.common import (ParamDef, ShardCtx, apply_rope, kv_eff_heads,
 
 NEG_INF = -1e30
 POS_SENTINEL = np.int32(2**30)   # k-slot "empty" marker (always masked out)
+QKV = "attention_qkv"            # checkpoint name of the q, k, v projections
 
 
 # ---------------------------------------------------------------------------
@@ -230,6 +232,33 @@ def attn_fwd(cfg: ModelConfig, ctx: ShardCtx, p: Dict, x: jnp.ndarray, *,
     return _gqa_decode(cfg, ctx, p, x, window=window, cache=cache, pos=pos)
 
 
+def _proj(x, w):
+    """A q, k or v projection, named for the layer checkpoint: its output,
+    taken before norms and RoPE, is what a saving policy keeps."""
+    return checkpoint_name(x @ w, QKV)
+
+
+def saved_bytes(cfg: ModelConfig, ctx: ShardCtx, batch: int, s_loc: int) -> int:
+    """Bytes of what ``_gqa_full`` names for the layer checkpoint over a
+    residual of (batch, s_loc, d): the q, k and v projections (``QKV``)
+    and, where the flash kernel takes the call, its output and f32
+    logsumexp (``fa_ops.RESIDUALS``).  MLA names nothing."""
+    if cfg.mla is not None:
+        return 0
+    head_tp = cfg.tp_strategy == "head" and ctx.model_axis is not None
+    seq_tp = cfg.tp_strategy == "seq" and ctx.model_axis is not None
+    dh, it = cfg.d_head, jnp.dtype(cfg.dtype).itemsize
+    if head_tp:
+        kv_eff, _ = kv_eff_heads(cfg.n_kv_heads, ctx.tp)
+        hq, kv, s = cfg.n_heads // ctx.tp, kv_eff // ctx.tp, s_loc * ctx.tp
+    else:
+        hq, kv, s = cfg.n_heads, cfg.n_kv_heads, s_loc
+    n = batch * s * (hq + 2 * kv) * dh * it
+    if not seq_tp and fa_ops.kernel_runs(s, dh, dh):
+        n += batch * hq * s * (dh * it + 4)
+    return n
+
+
 def _gqa_full(cfg, ctx, p, x, *, window, cache):
     head_tp = cfg.tp_strategy == "head" and ctx.model_axis is not None
     seq_tp = cfg.tp_strategy == "seq" and ctx.model_axis is not None
@@ -243,18 +272,18 @@ def _gqa_full(cfg, ctx, p, x, *, window, cache):
         kv_eff, _ = kv_eff_heads(cfg.n_kv_heads, ctx.tp)
         kv_loc = kv_eff // ctx.tp
         xg = ctx.gather_seq(x, compress=cfg.compress_gathers)   # (b, s, d)
-        q = _split_heads(xg @ p["wq"], hq_loc, dh)
-        k = _split_heads(xg @ p["wk"], kv_loc, dh)
-        v = _split_heads(xg @ p["wv"], kv_loc, dh)
+        q = _split_heads(_proj(xg, p["wq"]), hq_loc, dh)
+        k = _split_heads(_proj(xg, p["wk"]), kv_loc, dh)
+        v = _split_heads(_proj(xg, p["wv"]), kv_loc, dh)
         positions = np.arange(s, dtype=np.int32)     # known while tracing
         q_pos = k_pos = positions
     else:
         hq_loc, kv_loc = cfg.n_heads, cfg.n_kv_heads
         local_pos = (ctx.index() * s_loc + jnp.arange(s_loc, dtype=jnp.int32)
                      if seq_tp else np.arange(s_loc, dtype=np.int32))
-        q = _split_heads(x @ p["wq"], hq_loc, dh)
-        k_loc = _split_heads(x @ p["wk"], kv_loc, dh)
-        v_loc = _split_heads(x @ p["wv"], kv_loc, dh)
+        q = _split_heads(_proj(x, p["wq"]), hq_loc, dh)
+        k_loc = _split_heads(_proj(x, p["wk"]), kv_loc, dh)
+        v_loc = _split_heads(_proj(x, p["wv"]), kv_loc, dh)
         if cfg.qk_norm:
             q = _qk_normalize(q, p["q_norm"])
             k_loc = _qk_normalize(k_loc, p["k_norm"])

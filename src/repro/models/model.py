@@ -25,8 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.models import attention as attn
 from repro.models import moe as ffn
 from repro.models import recurrent as rec
@@ -88,6 +90,47 @@ def group_layers(cfg: ModelConfig, metas: List[LayerMeta],
 
 
 # ---------------------------------------------------------------------------
+# Layer checkpoint
+# ---------------------------------------------------------------------------
+
+RESIDUAL = "attention_residual"   # checkpoint name of the residual after attention
+# What a layer checkpoint under SAVE_MATMULS keeps beyond its inputs: the
+# outputs of the matmuls whose inputs the backward needs again (q, k, v; w_in
+# and w_gate), the flash kernel's output and logsumexp, and the residual
+# after attention (so that wo does not run again).  Norms, RoPE, the head
+# transposes and the activation stay recomputed.
+SAVED_NAMES = (attn.QKV, fa_ops.RESIDUALS, RESIDUAL, ffn.MLP_IN)
+SAVE_MATMULS = jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES)
+
+
+def _checkpointed(f, remat: bool, policy):
+    """The layer checkpoint around a unit of layers: none without
+    ``remat``; else ``policy`` says what the backward keeps (None: the
+    unit's inputs alone, so its whole forward runs again)."""
+    return jax.checkpoint(f, policy=policy) if remat else f
+
+
+def saved_bytes(cfg: ModelConfig, ctx: ShardCtx, batch: int, seq: int) -> int:
+    """Bytes that SAVE_MATMULS keeps in a train step over a local batch of
+    ``batch`` sequences of ``seq`` tokens: what SAVED_NAMES name in every
+    layer under the unit checkpoint (the unrolled prefix and tail layers
+    have none)."""
+    _, unit, n_units, _ = group_layers(cfg, layer_metas(cfg))
+    mctx = _mixer_ctx(cfg, ctx)
+    seq_sharded = (cfg.tp_strategy in ("head", "seq", "seq_ssm")
+                   and ctx.model_axis is not None)
+    s_loc = seq // ctx.tp if seq_sharded else seq
+    residual = batch * s_loc * cfg.d_model * jnp.dtype(cfg.dtype).itemsize
+    n = 0
+    for meta in unit:
+        if meta.kind == "attn":
+            n += residual + attn.saved_bytes(cfg, mctx, batch, s_loc)
+        if meta.d_ff and not meta.use_moe:
+            n += ffn.mlp_saved_bytes(cfg, mctx, batch, s_loc, meta.d_ff)
+    return n_units * n
+
+
+# ---------------------------------------------------------------------------
 # Block defs / fwd
 # ---------------------------------------------------------------------------
 
@@ -132,6 +175,8 @@ def block_fwd(cfg: ModelConfig, ctx: ShardCtx, mixer_ctx: ShardCtx,
     if cfg.post_norm:
         mix = apply_norm(cfg.norm_kind, mix, p["post_ln1"])
     x = x + mix
+    if meta.kind == "attn":
+        x = checkpoint_name(x, RESIDUAL)
     aux = jnp.zeros((), jnp.float32)
     if meta.d_ff or meta.use_moe:
         h = apply_norm(cfg.norm_kind, x, p["ln2"])
@@ -266,9 +311,13 @@ def forward(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray, *,
             pos: Optional[jnp.ndarray] = None,
             long_ctx: bool = False,
             remat: bool = True,
+            remat_policy=None,
             unroll: bool = False,
             ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
-    """Returns (hidden (b, s_loc, d), new_caches, aux_loss)."""
+    """Returns (hidden (b, s_loc, d), new_caches, aux_loss).  With
+    ``remat``, each scanned unit runs under a checkpoint whose
+    ``remat_policy`` (a ``jax.checkpoint`` policy; None recomputes the
+    whole unit) says what the backward keeps."""
     metas = layer_metas(cfg, long_ctx)
     prefix, unit, n_units, tail = group_layers(cfg, metas)
     mctx = _mixer_ctx(cfg, ctx)
@@ -307,7 +356,7 @@ def forward(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray, *,
             # appear explicitly (cost_analysis counts while-loop bodies ONCE, so
             # the dry-run/roofline lowers this form — EXPERIMENTS.md §Dry-run)
             unit_params = params["scan"]
-            body = (lambda f: jax.checkpoint(f)) if remat else (lambda f: f)
+
             def unit_fn(x, aux_acc, p_unit, c_unit):
                 ncs = []
                 for j, meta in enumerate(unit):
@@ -320,7 +369,8 @@ def forward(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray, *,
                 p_unit = jax.tree.map(lambda a: a[u], unit_params)
                 c_unit = (jax.tree.map(lambda a: a[u], caches["scan"])
                           if caches is not None else [None] * len(unit))
-                x, aux_total, ncs = body(unit_fn)(x, aux_total, p_unit, c_unit)
+                x, aux_total, ncs = _checkpointed(unit_fn, remat, remat_policy)(
+                    x, aux_total, p_unit, c_unit)
                 if caches is not None:
                     new_caches["scan"].append(ncs)
             if caches is not None:
@@ -339,7 +389,7 @@ def forward(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray, *,
                         aux_acc = aux_acc + aux
                     return (x, aux_acc), None
 
-                body = jax.checkpoint(unit_body) if remat else unit_body
+                body = _checkpointed(unit_body, remat, remat_policy)
                 (x, aux_total), _ = lax.scan(body, (x, aux_total), unit_params)
             else:
 
@@ -353,7 +403,7 @@ def forward(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray, *,
                         ncs.append(nc)
                     return (x, aux_acc), ncs
 
-                body = jax.checkpoint(unit_body_c) if remat else unit_body_c
+                body = _checkpointed(unit_body_c, remat, remat_policy)
                 (x, aux_total), scan_caches = lax.scan(
                     body, (x, aux_total), (unit_params, caches["scan"]))
                 new_caches["scan"] = scan_caches
@@ -382,7 +432,8 @@ def head_matrix(cfg: ModelConfig, params: Dict) -> jnp.ndarray:
 
 def lm_loss(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray,
             labels: jnp.ndarray, *, extra_emb: Optional[jnp.ndarray] = None,
-            remat: bool = True, chunk: int = 256, unroll: bool = False,
+            remat: bool = True, remat_policy=None, chunk: int = 256,
+            unroll: bool = False,
             ) -> Tuple[jnp.ndarray, Dict]:
     """Mean next-token cross-entropy (labels < 0 are masked).
 
@@ -390,7 +441,7 @@ def lm_loss(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray,
     (the embedding reduce-scatters into the seq-parallel residual).
     """
     x, _, aux = forward(cfg, ctx, params, ids, extra_emb=extra_emb,
-                        remat=remat, unroll=unroll)
+                        remat=remat, remat_policy=remat_policy, unroll=unroll)
     with jax.named_scope("lm_head"):
         w = head_matrix(cfg, params).astype(x.dtype)
 

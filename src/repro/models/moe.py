@@ -19,9 +19,12 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.models.common import ParamDef, ShardCtx, activation
+
+MLP_IN = "mlp_in"     # checkpoint name of the w_in and w_gate outputs
 
 
 # ---------------------------------------------------------------------------
@@ -56,15 +59,26 @@ def mlp_fwd(cfg: ModelConfig, ctx: ShardCtx, p: Dict, x: jnp.ndarray,
         xg = ctx.gather_seq(x, compress=cfg.compress_gathers)
     else:
         xg = x
-    h = xg @ p["w_in"]
+    h = checkpoint_name(xg @ p["w_in"], MLP_IN)
     if cfg.gated_mlp:
-        h = activation(cfg.act, h) * (xg @ p["w_gate"])
+        h = activation(cfg.act, h) * checkpoint_name(xg @ p["w_gate"], MLP_IN)
     else:
         h = activation(cfg.act, h)
     y = h @ p["w_out"]                                   # partial sums if sharded
     if shard_ff:
         y = ctx.scatter_seq(y) if sequence_parallel else ctx.psum_model(y)
     return y
+
+
+def mlp_saved_bytes(cfg: ModelConfig, ctx: ShardCtx, batch: int, s_loc: int,
+                    d_ff: int) -> int:
+    """Bytes of what ``mlp_fwd`` names ``MLP_IN`` for the layer checkpoint
+    over a sequence-parallel residual of (batch, s_loc, d): the w_in output
+    and, gated, the w_gate output."""
+    shard_ff = cfg.tp_strategy in ("head", "seq") and ctx.model_axis is not None
+    s, ff = (s_loc * ctx.tp, d_ff // ctx.tp) if shard_ff else (s_loc, d_ff)
+    n = 2 if cfg.gated_mlp else 1
+    return batch * s * ff * n * jnp.dtype(cfg.dtype).itemsize
 
 
 # ---------------------------------------------------------------------------

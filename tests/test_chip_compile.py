@@ -123,3 +123,45 @@ def test_flash_attention_compiles_at_the_train_cell_shape(
     for part in ("fwd", "dq", "dkv"):
         assert any(part in n.rsplit("/", 2)[-2] for n in names), (part, names)
     assert all(scopes.scope_of(n) == "attention_core" for n in names), names
+
+
+def test_olmo_train_step_saves_its_matmul_outputs(one_chip, no_persistent_cache,
+                                                  monkeypatch):
+    """``olmo-1b-4l.cvap3``'s whole train step (the cell's configuration,
+    8 x 2048 tokens, CVAP(3, 0.05), Adam) with the layer checkpoint's saving
+    plan, as a device that reports 16 GiB chooses it: the forward flash
+    kernel is one instruction of the scanned forward, so it runs once a
+    layer and the backward runs it no more, and the program fits the v5e's
+    16 GiB."""
+    import json
+    import re
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from bench.train_cell import model_config
+    from repro.configs import ConsistencySpec, TrainConfig
+    from repro.launch import steps
+    from repro.launch.state import init_train_state
+    monkeypatch.setenv("REPRO_PALLAS", "on")
+    monkeypatch.setattr(steps, "device_bytes_limit", lambda mesh: 16 * 2**30)
+    with open(os.path.join(root, "bench", "configs", "olmo-1b-4l.json")) as f:
+        cfg = model_config(json.load(f), "olmo-1b")
+    tcfg = TrainConfig(arch=cfg.name, lr=3e-4, warmup_steps=0,
+                       optimizer="adam", consistency=ConsistencySpec(
+                           model="cvap", staleness=3, value_bound=0.05))
+    state = jax.eval_shape(lambda k: init_train_state(cfg, tcfg, 1, 1, k),
+                           jax.random.key(0))
+    state = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), state)
+    batch = {k: _sds((8, 2048), jnp.int32, one_chip) for k in ("ids", "labels")}
+    compiled = steps.make_train_step(cfg, tcfg, None).lower(
+        state, batch).compile()
+    text = compiled.as_text()
+    names = [re.compile(r'op_name="([^"]*)"').search(text, m.end()).group(1)
+             for m in re.finditer(r'custom_call_target="tpu_custom_call"',
+                                  text)]
+    fwd = [n for n in names if "splash_mha_fwd" in n]
+    assert len(fwd) == 1 and "transpose(" not in fwd[0], names
+    m = compiled.memory_analysis()
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert peak < 16 * 2**30, peak

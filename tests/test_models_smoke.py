@@ -77,16 +77,34 @@ def test_unroll_matches_scan(arch):
                                atol=2e-5)
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
-def test_remat_matches_no_remat_gradients(arch):
+# every arch under full recompute (ids as before) and under the saving
+# policy; one dense model whose attention takes the interpreted flash kernel,
+# so that the kernel's named residuals are saved too
+REMAT_CASES = ([pytest.param(a, "full", id=a) for a in ARCH_IDS]
+               + [pytest.param(a, "save", id=f"{a}-save") for a in ARCH_IDS]
+               + [pytest.param("olmo-1b", "save-flash", id="olmo-1b-save-flash")])
+
+
+@pytest.mark.parametrize("arch,plan", REMAT_CASES)
+def test_remat_matches_no_remat_gradients(arch, plan, monkeypatch):
+    """Gradients through the layer checkpoint, with full recompute or with
+    the matmul outputs saved (``M.SAVE_MATMULS``), equal those without it."""
     cfg = _cfg(arch)
+    s = 32
+    if plan == "save-flash":
+        monkeypatch.setenv("REPRO_PALLAS", "interpret")
+        cfg = dataclasses.replace(cfg, n_heads=2, n_kv_heads=2, d_head=128,
+                                  d_model=256)
+        s = 128
+    policy = M.SAVE_MATMULS if plan.startswith("save") else None
     ctx = ShardCtx()
     params = instantiate_tree(M.model_defs(cfg, 1), jax.random.key(2))
-    batch = _batch(cfg, seed=4)
+    batch = _batch(cfg, s=s, seed=4)
 
     def loss(p, remat):
         l, _ = M.lm_loss(cfg, ctx, p, batch["ids"], batch["labels"],
-                         extra_emb=batch.get("extra_emb"), remat=remat)
+                         extra_emb=batch.get("extra_emb"), remat=remat,
+                         remat_policy=policy)
         return l
 
     g1 = jax.grad(lambda p: loss(p, True))(params)
