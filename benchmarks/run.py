@@ -1,8 +1,7 @@
-"""Benchmark harness — one section per paper table/figure + the roofline.
+"""Benchmark harness — one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV per the repo convention, plus the
-full result dicts, and regenerates results/roofline.md when dry-run
-artifacts exist.
+full result dicts.
 
     PYTHONPATH=src python -m benchmarks.run
 """
@@ -105,25 +104,6 @@ def main() -> None:
                           "BENCH_convergence.json")
     bench_convergence.write_json(cv_rows, cv_out)
     print(f"# wrote {cv_out}")
-
-    print("# --- kernel reference-path microbenchmarks ---")
-    from benchmarks import bench_kernels
-    for r in bench_kernels.run():
-        all_rows.append(dict(r))
-        print(_csv_line(r))
-
-    print("# --- roofline (from dry-run artifacts) ---")
-    from benchmarks import roofline
-    rows = roofline.load_all()
-    if rows:
-        for r in sorted(rows, key=lambda r: (r["arch"], r["shape"])):
-            print(f"roofline/{r['arch']}/{r['shape']},0.0,"
-                  f"bound={r['dominant']};step_s={r['bound_step_s']:.4g};"
-                  f"useful={r['useful_fraction']:.2f};"
-                  f"peak_gib={r['peak_gib']:.1f}")
-        roofline.main()
-    else:
-        print("# (no dry-run artifacts; run repro.launch.dryrun --all first)")
 
     from benchmarks import common
     out = os.path.join(os.path.dirname(__file__), "..", "results",

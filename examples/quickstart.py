@@ -13,7 +13,7 @@ from repro.launch.train import run
 
 def main() -> None:
     # the reduced OLMo variant runs on CPU; swap for get_config("olmo-1b")
-    # and a production mesh on real hardware
+    # and a ("data", "model") mesh (launch/mesh.py make_mesh) on real hardware
     cfg = dataclasses.replace(reduced_config("olmo-1b"), dtype="float32")
 
     with tempfile.TemporaryDirectory() as ckpt_dir:

@@ -1,5 +1,6 @@
 """Serving demo: prefill a batch of prompts, then batched greedy decode with
-ring KV caches — the same prefill/serve steps the multi-pod dry-run lowers.
+ring KV caches, through the library's prefill and serve steps
+(``repro.launch.steps``).
 
     PYTHONPATH=src python examples/serve_demo.py [--arch gemma2-2b]
 

@@ -114,9 +114,9 @@ class ModelConfig:
     frontend: Optional[FrontendConfig] = None
 
     # --- distribution --------------------------------------------------------
-    tp_strategy: str = "head"         # "head" | "seq" | "replicated"  (see DESIGN §6)
+    tp_strategy: str = "head"         # "head" | "seq" | "replicated"
     # long-context mode: attention archs fall back to sliding-window caches so
-    # that the 500k decode shape has bounded memory (DESIGN §6).
+    # that a 500k-token decode has bounded memory.
     long_context_window: int = 4096
 
     # --- numerics ------------------------------------------------------------
@@ -142,73 +142,9 @@ class ModelConfig:
         pat = self.layer_pattern
         return tuple(pat[i % len(pat)] for i in range(self.n_layers))
 
-    def param_count(self) -> int:
-        """Approximate parameter count (used for roofline MODEL_FLOPS)."""
-        d = self.d_model
-        total = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        for kind in self.layer_kinds():
-            if kind == "attn":
-                if self.mla is not None:
-                    m = self.mla
-                    qd = m.qk_nope_head_dim + m.qk_rope_head_dim
-                    total += d * self.n_heads * qd                       # q proj
-                    total += d * (m.kv_lora_rank + m.qk_rope_head_dim)   # kv down
-                    total += m.kv_lora_rank * self.n_heads * (m.qk_nope_head_dim + m.v_head_dim)
-                    total += self.n_heads * m.v_head_dim * d             # out
-                else:
-                    total += d * self.n_heads * self.d_head              # q
-                    total += 2 * d * self.n_kv_heads * self.d_head       # k,v
-                    total += self.n_heads * self.d_head * d              # out
-            elif kind == "rec":
-                r = self.recurrent
-                if r.kind == "rglru":
-                    w = r.width
-                    total += 2 * d * w            # in projections (x, gate)
-                    total += w * d                # out projection
-                    total += r.conv_width * w     # causal conv
-                    total += 3 * w                # lru gates/params (approx)
-                else:  # mamba2
-                    w = r.width
-                    nh = w // r.head_dim
-                    total += d * (2 * w + 2 * r.n_groups * r.d_state + nh)
-                    total += r.conv_width * (w + 2 * r.n_groups * r.d_state)
-                    total += w * d
-                    total += 2 * nh
-            if kind in ("attn", "rec"):
-                total += self._ffn_params_for_layer()
-        return total
-
-    def _ffn_params_for_layer(self) -> int:
-        d = self.d_model
-        if self.moe is not None:
-            m = self.moe
-            p = m.n_experts * (3 if self.gated_mlp else 2) * d * m.d_expert
-            p += d * m.n_experts                                         # router
-            if m.n_shared_experts:
-                p += (3 if self.gated_mlp else 2) * d * m.d_shared
-            return p
-        mult = 3 if self.gated_mlp else 2
-        return mult * d * self.d_ff
-
-    def active_param_count(self) -> int:
-        """Params touched per token (MoE: routed top-k only) — for 6·N_active·D."""
-        if self.moe is None:
-            return self.param_count()
-        d = self.d_model
-        m = self.moe
-        total = self.param_count()
-        # subtract inactive routed experts
-        per_expert = (3 if self.gated_mlp else 2) * d * m.d_expert
-        n_moe_layers = sum(
-            1 for i, k in enumerate(self.layer_kinds())
-            if k == "attn" and i >= m.first_dense_layers
-        )
-        total -= n_moe_layers * (m.n_experts - m.top_k) * per_expert
-        return total
-
 
 # ---------------------------------------------------------------------------
-# Input shapes (assigned)
+# Input shapes (prefill and serve steps)
 # ---------------------------------------------------------------------------
 
 
@@ -238,7 +174,6 @@ class ConsistencySpec:
 @dataclass(frozen=True)
 class TrainConfig:
     arch: str = "olmo-1b"
-    shape: str = "train_4k"
     steps: int = 100
     lr: float = 3e-4
     warmup_steps: int = 10
@@ -247,14 +182,12 @@ class TrainConfig:
     seed: int = 0
     consistency: ConsistencySpec = field(default_factory=ConsistencySpec)
     remat: bool = True
-    microbatch: int = 0               # 0 = no microbatching
     log_every: int = 10
     checkpoint_dir: str = ""
     checkpoint_every: int = 0
-    # beyond-paper options (see EXPERIMENTS.md §Perf)
+    # beyond the paper: a bf16 sync all-reduce and a narrower state dtype
     quantize_sync: bool = False       # bf16 delta all-reduce (error feedback)
-    hierarchical_sync: int = 0        # sync across pods every k-th sync
-    state_dtype: str = "float32"      # delta + Adam moments storage dtype epoch
+    state_dtype: str = "float32"      # delta + Adam moments storage dtype
 
 
 def replace(cfg, **kw):

@@ -2,8 +2,7 @@
 
 Besides the 10 full assigned configs, every architecture exposes a REDUCED
 smoke variant (≤2 layers, d_model ≤ 512, ≤4 experts) used by the per-arch CPU
-smoke tests; the full configs are only ever lowered via the dry-run
-(ShapeDtypeStruct — no allocation).
+smoke tests.
 """
 from __future__ import annotations
 
@@ -32,8 +31,8 @@ ARCHS = {
 }
 
 # ---------------------------------------------------------------------------
-# Beyond-paper performance variants (EXPERIMENTS.md §Perf) — NOT part of the
-# assigned 10; selectable for A/B dry-runs.
+# Beyond-paper performance variants — NOT part of the assigned 10;
+# selectable by name for A/B runs.
 # ---------------------------------------------------------------------------
 ARCHS["mamba2-130m-sp"] = dataclasses.replace(
     _mamba2, name="mamba2-130m-sp", tp_strategy="seq_ssm")

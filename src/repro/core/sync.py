@@ -1,4 +1,4 @@
-"""TPU/SPMD adaptation of the consistency models (DESIGN.md §3, layer 2).
+"""TPU/SPMD adaptation of the consistency models (the SPMD layer).
 
 On a lockstep SPMD mesh, each data-parallel replica keeps its own *drifting*
 copy of the parameters plus an accumulated unsynchronized delta ``δ``.
@@ -9,8 +9,8 @@ Controller decides per step whether the delta all-reduce runs:
     SSP/CAP/ESSP(s): every s-th step (staleness ≤ s by construction; in
             lockstep SPMD the push-early vs push-at-clock distinction AND
             ESSP's eager server push both collapse — every sync epoch is a
-            full exchange, so the server can't be "ahead" of it; see
-            DESIGN.md §3 and arXiv:1410.8043).
+            full exchange, so the server can't be "ahead" of it;
+            arXiv:1410.8043).
     VAP(v): when any replica's ‖δ‖∞ would exceed v_thr — one scalar pmax per
             step, the TPU analogue of the paper's per-worker blocking.
     CVAP  : clock OR value trigger.
@@ -22,14 +22,9 @@ The sync itself is ``params ← params + (Σ_replicas δ) − δ`` — the assoc
 and commutative update rule of §2, so FIFO/ordering concerns vanish and the
 result equals the paper's "all updates visible" state.
 
-Beyond-paper options (EXPERIMENTS.md §Perf):
-  * ``compress="bf16"``   — deltas all-reduce in bf16 with fp32 error-feedback
-    residual (the VAP bound caps |δ| and hence the quantization error).
-  * ``hierarchy=k``       — two-level sync: every trigger syncs within the
-    pod ('data' axis); only every k-th sync crosses pods ('pod' axis),
-    exploiting the ICI≫DCI bandwidth gap.  Cross-pod contributions accumulate
-    in a separate ``pod_pending`` buffer (replicated within a pod) so nothing
-    is double-counted.  Effective staleness: s intra-pod, k·s cross-pod.
+Beyond the paper, ``compress="bf16"`` all-reduces the deltas in bf16 with an
+fp32 error-feedback residual (the VAP bound caps |δ| and hence the
+quantization error).
 """
 from __future__ import annotations
 
@@ -55,15 +50,13 @@ PyTree = Any
 class SyncState:
     delta: PyTree                  # accumulated unsynchronized updates
     residual: PyTree               # error-feedback residual (compress mode)
-    pod_pending: PyTree            # intra-pod aggregates not yet crossed pods
     steps_since_sync: jnp.ndarray  # i32 scalar
     sync_count: jnp.ndarray        # i32 scalar — total sync epochs so far
     max_update_mag: jnp.ndarray    # f32 scalar — running max ‖u‖∞ (bound check)
     max_update_l2: jnp.ndarray     # f32 scalar — running max ‖u‖₂ (elastic)
 
 
-def init_sync_state(params: PyTree, hierarchy: int = 0,
-                    compress: Optional[str] = None,
+def init_sync_state(params: PyTree, compress: Optional[str] = None,
                     dtype=None) -> SyncState:
     """dtype: storage dtype of the delta accumulator (bf16 halves both the
     resident bytes and the sync all-reduce volume; the VAP bound caps |δ|,
@@ -74,7 +67,6 @@ def init_sync_state(params: PyTree, hierarchy: int = 0,
     return SyncState(
         delta=zeros(),
         residual=zeros() if compress else none_tree,
-        pod_pending=zeros() if hierarchy and hierarchy > 1 else none_tree,
         steps_since_sync=jnp.zeros((), jnp.int32),
         sync_count=jnp.zeros((), jnp.int32),
         max_update_mag=jnp.zeros((), jnp.float32),
@@ -137,8 +129,8 @@ def sync_trigger(policy: Policy, sync_state: SyncState, new_delta: PyTree,
     the data-parallel axes PLUS the model axis when parameters are
     tensor-sharded (each model shard only sees its slice's ‖δ‖∞; all shards
     must take the same cond branch).  The paper's per-worker block becomes a
-    mesh-wide sync epoch — conservative, so the VAP invariant still holds
-    (DESIGN.md §3).
+    mesh-wide sync epoch — conservative, so the VAP invariant still holds:
+    a replica syncs no later than its own bound would make it.
     """
     axes = tuple(trigger_axes) if trigger_axes is not None else tuple(dp_axes)
     trig = jnp.zeros((), jnp.bool_)
@@ -173,8 +165,6 @@ def apply_and_sync(
     policy: Policy,
     dp_axes: Sequence[str],
     compress: Optional[str] = None,
-    hierarchy: int = 0,
-    pod_axis: Optional[str] = None,
     trigger_axes: Optional[Sequence[str]] = None,
 ) -> Tuple[PyTree, SyncState, jnp.ndarray]:
     """Apply a local optimizer update, then maybe synchronize replicas.
@@ -195,15 +185,11 @@ def apply_and_sync(
     trig = sync_trigger(policy, sync_state, new_delta, dp_axes,
                         trigger_axes=trigger_axes)
 
-    hierarchical = bool(hierarchy and hierarchy > 1 and pod_axis
-                        and pod_axis in dp_axes)
-
     if not dp_axes:
         # single replica: every "sync" is a no-op but the clock still ticks
         new_state = SyncState(
             delta=jax.tree.map(lambda d: jnp.where(trig, jnp.zeros_like(d), d), new_delta),
             residual=sync_state.residual,
-            pod_pending=sync_state.pod_pending,
             steps_since_sync=jnp.where(trig, 0, sync_state.steps_since_sync + 1).astype(jnp.int32),
             sync_count=(sync_state.sync_count + trig.astype(jnp.int32)),
             max_update_mag=umag,
@@ -218,46 +204,22 @@ def apply_and_sync(
         return comp, tree_sub(send, comp)
 
     def do_sync(operand):
-        p, d, r, pend, cnt = operand
-        if hierarchical:
-            intra = tuple(a for a in dp_axes if a != pod_axis)
-            if compress:
-                d_send, r = compressed_send(d, r)
-            else:
-                d_send = d
-            tot_intra = _psum_tree(d_send, intra, compress)
-            p = tree_add(p, tree_sub(tot_intra, d_send))
-            pend = tree_add(pend, tot_intra)
-            cross = (cnt % hierarchy) == (hierarchy - 1)
-
-            def do_cross(p, pend):
-                tot = _psum_tree(pend, (pod_axis,), compress)
-                return tree_add(p, tree_sub(tot, pend)), tree_zeros(pend)
-
-            p, pend = lax.cond(cross, do_cross, lambda p, pend: (p, pend), p, pend)
-            return p, tree_zeros(d), r, pend
-
+        p, d, r = operand
         if compress:
             d_send, r = compressed_send(d, r)
         else:
             d_send = d
         tot = _psum_tree(d_send, dp_axes, compress)
         p = tree_add(p, tree_sub(tot, d_send))
-        return p, tree_zeros(d), r, pend
+        return p, tree_zeros(d), r
 
-    def no_sync(operand):
-        p, d, r, pend, _ = operand
-        return p, d, r, pend
-
-    params, delta_out, residual, pod_pending = lax.cond(
-        trig, do_sync, no_sync,
-        (params, new_delta, sync_state.residual, sync_state.pod_pending,
-         sync_state.sync_count))
+    params, delta_out, residual = lax.cond(
+        trig, do_sync, lambda operand: operand,
+        (params, new_delta, sync_state.residual))
 
     new_state = SyncState(
         delta=delta_out,
         residual=residual,
-        pod_pending=pod_pending,
         steps_since_sync=jnp.where(trig, 0, sync_state.steps_since_sync + 1).astype(jnp.int32),
         sync_count=sync_state.sync_count + trig.astype(jnp.int32),
         max_update_mag=umag,

@@ -7,8 +7,7 @@ Each kernel lives in its own subpackage with three files:
 
 Dispatch (ops.py): the Pallas kernel runs on TPU, or anywhere when
 ``REPRO_PALLAS=interpret`` is set (tests validate the kernel body on CPU via
-``interpret=True``); otherwise the jnp reference runs — which is what the
-CPU dry-run lowers and the roofline reads.
+``interpret=True``); otherwise the jnp reference runs.
 """
 import os
 

@@ -4,8 +4,7 @@ State-space duality: within a chunk the recurrence is computed as a masked
 quadratic attention-like product; across chunks states are passed through a
 sequential decay recurrence.
 
-Memory discipline (this path is also what the CPU dry-run lowers, so its
-buffers land in the roofline memory analysis):
+Memory discipline (this path runs wherever the Pallas kernel does not):
   * B/C stay at GROUP granularity — never `repeat`ed to heads;
   * bulk tensors stay in the input dtype (bf16 in production), only the
     decay/cumsum bookkeeping is f32;

@@ -1,9 +1,7 @@
-"""Partition specs and global shapes for state, batches, and caches.
+"""Partition specs for the train state, batches, and caches.
 
-The "dp" marker stands for the data-parallel mesh axes and is resolved to
-``('data',)`` or ``('pod', 'data')`` per mesh.  ``input_specs`` returns
-ShapeDtypeStructs with attached NamedShardings — the dry-run lowers against
-them without allocating anything.
+The "dp" marker stands for the data-parallel mesh axis and is resolved to
+``'data'`` per mesh (``mesh.dp_axes_of``).
 """
 from __future__ import annotations
 
@@ -70,7 +68,6 @@ def train_state_pspecs(cfg: ModelConfig, tcfg: TrainConfig, tp: int) -> TrainSta
                      count=P()),
         sync=SyncState(delta=mirror(abs_local.sync.delta),
                        residual=mirror(abs_local.sync.residual),
-                       pod_pending=mirror(abs_local.sync.pod_pending),
                        steps_since_sync=P(), sync_count=P(),
                        max_update_mag=P(), max_update_l2=P()),
         step=P(),
@@ -79,37 +76,14 @@ def train_state_pspecs(cfg: ModelConfig, tcfg: TrainConfig, tp: int) -> TrainSta
                         is_leaf=lambda x: isinstance(x, P))
 
 
-def abstract_train_state(cfg: ModelConfig, tcfg: TrainConfig, tp: int,
-                         dp: int) -> TrainState:
-    abs_local = jax.eval_shape(
-        lambda k: init_local_state(cfg, tcfg, tp, k), jax.random.key(0))
-    return jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct((dp,) + s.shape, s.dtype), abs_local)
-
-
 # ---------------------------------------------------------------------------
 # Batch specs
 # ---------------------------------------------------------------------------
 
 
 def _batch_dp(B: int, dp_total: int) -> Optional[str]:
-    """Shard batch over dp only when divisible (long_500k has B=1)."""
+    """Shard batch over dp only when divisible (a batch of 1 is not)."""
     return DP if dp_total > 1 and B % dp_total == 0 else None
-
-
-def train_batch_specs(cfg: ModelConfig, shape: InputShape, dp_total: int,
-                      ) -> Tuple[Dict, Dict]:
-    B, S = shape.global_batch, shape.seq_len
-    bspec = _batch_dp(B, dp_total)
-    # ids/labels are REPLICATED over the model axis (vocab-parallel embedding)
-    abst = {"ids": jax.ShapeDtypeStruct((B, S), jnp.int32),
-            "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
-    spec = {"ids": P(bspec, None), "labels": P(bspec, None)}
-    if cfg.frontend is not None:
-        abst["extra_emb"] = jax.ShapeDtypeStruct(
-            (B, cfg.frontend.n_embeds, cfg.d_model), jnp.dtype(cfg.dtype))
-        spec["extra_emb"] = P(bspec, None, None)
-    return abst, spec
 
 
 def prefill_batch_specs(cfg: ModelConfig, shape: InputShape, dp_total: int,
@@ -179,26 +153,3 @@ def model_cache_pspecs(cfg: ModelConfig, B: int, dp_total: int,
     return {"prefix": [block(m) for m in prefix],
             "scan": [stack(block(m)) for m in unit],
             "tail": [block(m) for m in tail]}
-
-
-def global_cache_abstract(cfg: ModelConfig, shape: InputShape, dp_total: int,
-                          tp: int, long_ctx: bool = False) -> Dict:
-    """Global ShapeDtypeStructs for the decode caches: take the LOCAL cache
-    defs and expand each dim by the size of the mesh axis its spec names."""
-    B = shape.global_batch
-    bspec = _batch_dp(B, dp_total)
-    b_loc = B // dp_total if bspec is not None else B
-    local = M.model_cache_defs(cfg, tp, b_loc, shape.seq_len, long_ctx)
-    specs = model_cache_pspecs(cfg, B, dp_total, long_ctx)
-
-    def globalize(sds: jax.ShapeDtypeStruct, spec: P) -> jax.ShapeDtypeStruct:
-        shape_ = list(sds.shape)
-        for i, ax in enumerate(spec):
-            if ax == DP:
-                shape_[i] *= dp_total
-            elif ax == "model":
-                shape_[i] *= tp
-        return jax.ShapeDtypeStruct(tuple(shape_), sds.dtype)
-
-    return jax.tree.map(globalize, local, specs,
-                        is_leaf=lambda x: isinstance(x, (jax.ShapeDtypeStruct, P)))

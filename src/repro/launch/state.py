@@ -41,7 +41,7 @@ def init_local_state(cfg: ModelConfig, tcfg: TrainConfig, tp: int,
     return TrainState(
         params=params,
         opt=init_opt_state(params, tcfg.optimizer, dtype=sdt),
-        sync=init_sync_state(params, hierarchy=tcfg.hierarchical_sync,
+        sync=init_sync_state(params,
                              compress="bf16" if tcfg.quantize_sync else None,
                              dtype=sdt),
         step=jnp.zeros((), jnp.int32),

@@ -106,7 +106,6 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
     opt_fn = optimizer_update(tcfg.optimizer)
     rep_mask = _replicated_leaf_mask(cfg, ctx.tp)
     all_axes = tuple(ctx.dp_axes) + ((ctx.model_axis,) if ctx.model_axis else ())
-    pod_axis = "pod" if (mesh is not None and "pod" in mesh.axis_names) else None
     bytes_limit = device_bytes_limit(mesh)
 
     def local_step(state: TrainState, batch: Dict):
@@ -141,7 +140,6 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
             params, sync_state, synced = apply_and_sync(
                 st.params, st.sync, update, policy, ctx.dp_axes,
                 compress="bf16" if tcfg.quantize_sync else None,
-                hierarchy=tcfg.hierarchical_sync, pod_axis=pod_axis,
                 trigger_axes=all_axes)
             # in the scope: XLA roots the fused parameter and delta writes
             # at this reshape, and a fusion is named by its root
@@ -169,7 +167,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh,
 
     dp_axes = mesh_lib.dp_axes_of(mesh)
     state_spec = S.resolve_tree(S.train_state_pspecs(cfg, tcfg, ctx.tp), dp_axes)
-    bdp = dp_axes if len(dp_axes) > 1 else dp_axes[0]
+    bdp = dp_axes[0]
     batch_spec = {"ids": P(bdp, None), "labels": P(bdp, None)}
     if cfg.frontend is not None:
         batch_spec["extra_emb"] = P(bdp, None, None)

@@ -1,8 +1,8 @@
 """Training driver.
 
-On real hardware this runs under the production mesh; on CPU it runs
-single-device with the reduced ("smoke") architecture variants, which is
-what the end-to-end example uses:
+With several devices it runs one data-parallel replica on each; on CPU it
+runs single-device with the reduced ("smoke") architecture variants, which
+is what the end-to-end example uses:
 
     PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --smoke \\
         --steps 200 --consistency cvap --staleness 4 --vthr 0.05
@@ -112,7 +112,6 @@ def main() -> None:
     ap.add_argument("--staleness", type=int, default=0)
     ap.add_argument("--vthr", type=float, default=0.0)
     ap.add_argument("--quantize-sync", action="store_true")
-    ap.add_argument("--hierarchical-sync", type=int, default=0)
     ap.add_argument("--checkpoint-dir", default="")
     ap.add_argument("--checkpoint-every", type=int, default=0)
     ap.add_argument("--seed", type=int, default=0)
@@ -129,7 +128,6 @@ def main() -> None:
                                     staleness=args.staleness,
                                     value_bound=args.vthr),
         quantize_sync=args.quantize_sync,
-        hierarchical_sync=args.hierarchical_sync,
         checkpoint_dir=args.checkpoint_dir,
         checkpoint_every=args.checkpoint_every,
     )

@@ -4,7 +4,6 @@ Parameter handling has one source of truth: :func:`param_defs` builders
 return a pytree of :class:`ParamDef` (shape + sharded dims + init rule).
 From it we derive
   * concrete arrays            (``instantiate``)
-  * ``jax.ShapeDtypeStruct``s  (``abstract``)      — for the dry-run
   * ``PartitionSpec``s         (``pspec``)         — for pjit/shard_map
 
 Model forward code is written against LOCAL (per-device) shapes inside
@@ -144,9 +143,6 @@ class ParamDef:
             return full.reshape(self.shape)
         raise ValueError(f"unknown init {self.init!r}")
 
-    def abstract(self) -> jax.ShapeDtypeStruct:
-        return jax.ShapeDtypeStruct(self.shape, self.dtype)
-
     def pspec(self) -> P:
         if not self.shard:
             return P()
@@ -159,11 +155,6 @@ def instantiate_tree(defs: PyTree, key: jax.Array) -> PyTree:
     keys = jax.random.split(key, len(leaves))
     vals = [d.instantiate(k) for d, k in zip(leaves, keys)]
     return jax.tree.unflatten(treedef, vals)
-
-
-def abstract_tree(defs: PyTree) -> PyTree:
-    return jax.tree.map(lambda d: d.abstract(), defs,
-                        is_leaf=lambda x: isinstance(x, ParamDef))
 
 
 def pspec_tree(defs: PyTree) -> PyTree:

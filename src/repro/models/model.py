@@ -353,8 +353,7 @@ def forward(cfg: ModelConfig, ctx: ShardCtx, params: Dict, ids: jnp.ndarray, *,
         # --- scanned units --------------------------------------------------
         if n_units and unroll:
             # python-loop over units: big HLO, but per-layer FLOPs/collectives
-            # appear explicitly (cost_analysis counts while-loop bodies ONCE, so
-            # the dry-run/roofline lowers this form — EXPERIMENTS.md §Dry-run)
+            # appear explicitly (cost_analysis counts while-loop bodies ONCE)
             unit_params = params["scan"]
 
             def unit_fn(x, aux_acc, p_unit, c_unit):

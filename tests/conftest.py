@@ -50,7 +50,7 @@ def run_devices_subprocess(code: str, n_devices: int = 8,
                            timeout: int = 600) -> str:
     """Run `code` in a subprocess with N fake host devices.
 
-    The dry-run flag must be set before jax initializes, so multi-device
+    The device-count flag must be set before jax initializes, so multi-device
     tests run out-of-process (the main test process keeps 1 device, per the
     brief)."""
     env = dict(os.environ)

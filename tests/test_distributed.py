@@ -51,7 +51,7 @@ from repro.configs import reduced_config, ConsistencySpec, TrainConfig
 from repro.launch import mesh as mesh_lib, steps, specs as S
 from repro.launch.state import init_train_state, init_local_state, add_dp_axis
 
-mesh = mesh_lib.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
 cfg = dataclasses.replace(reduced_config("olmo-1b"), dtype="float32")
 tcfg = TrainConfig(arch="olmo-1b", optimizer="adam", lr=1e-3, warmup_steps=0,
                    consistency=ConsistencySpec(model="bsp"))
@@ -59,7 +59,7 @@ rng = np.random.default_rng(0)
 ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (8, 17)), jnp.int32)
 batch = {"ids": ids[:, :-1], "labels": ids[:, 1:]}
 state = init_train_state(cfg, tcfg, tp=2, dp=4, key=jax.random.key(0))
-state_spec = S.resolve_tree(S.train_state_pspecs(cfg, tcfg, 2), ("pod", "data"))
+state_spec = S.resolve_tree(S.train_state_pspecs(cfg, tcfg, 2), ("data",))
 state = jax.device_put(state, S.shardings(state_spec, mesh))
 fn = steps.make_train_step(cfg, tcfg, mesh, donate=False)
 _, md = fn(state, batch)
@@ -121,7 +121,7 @@ from repro.launch import mesh as mesh_lib, steps, specs as S
 from repro.models.common import instantiate_tree, pspec_tree, ShardCtx
 from repro.models import model as M
 
-mesh = mesh_lib.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
 for arch in ["gemma2-2b", "musicgen-medium", "mamba2-130m"]:
     cfg = dataclasses.replace(reduced_config(arch), dtype="float32")
     defs = M.model_defs(cfg, 2)
@@ -148,23 +148,23 @@ for arch in ["gemma2-2b", "musicgen-medium", "mamba2-130m"]:
     assert out.count("OK") == 3
 
 
-def test_hierarchical_and_compressed_sync(devices8):
-    """Beyond-paper options lower and run on a pod×data×model mesh."""
+def test_compressed_sync(devices8):
+    """The bf16 sync all-reduce lowers and runs on a data×model mesh."""
     out = devices8("""
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from repro.configs import reduced_config, ConsistencySpec, TrainConfig
 from repro.launch import mesh as mesh_lib, steps, specs as S
 from repro.launch.state import init_train_state
-mesh = mesh_lib.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = mesh_lib.make_mesh((4, 2), ("data", "model"))
 cfg = dataclasses.replace(reduced_config("olmo-1b"), dtype="float32")
 rng = np.random.default_rng(0)
 ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (8, 17)), jnp.int32)
 batch = {"ids": ids[:, :-1], "labels": ids[:, 1:]}
 tcfg = TrainConfig(arch="olmo-1b", optimizer="adam", lr=1e-3, warmup_steps=0,
                    consistency=ConsistencySpec(model="cap", staleness=1),
-                   quantize_sync=True, hierarchical_sync=2)
+                   quantize_sync=True)
 state = init_train_state(cfg, tcfg, tp=2, dp=4, key=jax.random.key(0))
-spec = S.resolve_tree(S.train_state_pspecs(cfg, tcfg, 2), ("pod", "data"))
+spec = S.resolve_tree(S.train_state_pspecs(cfg, tcfg, 2), ("data",))
 state = jax.device_put(state, S.shardings(spec, mesh))
 fn = steps.make_train_step(cfg, tcfg, mesh, donate=False)
 losses = []

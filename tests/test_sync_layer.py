@@ -146,65 +146,8 @@ def test_trigger_uniform_with_trigger_axes_noop_single():
 
 
 # ---------------------------------------------------------------------------
-# hierarchical + compressed paths on a real 2-pod mesh (8 fake devices)
+# the compressed path on a real 8-replica mesh (8 fake devices)
 # ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_hierarchical_pod_pending_no_double_count(devices8):
-    """Integer per-replica updates on a (pod=2, data=4) mesh with
-    hierarchy=3: every intermediate state must match the closed form
-    'own-pod updates every epoch + peer-pod updates only at cross epochs' —
-    any double counting of ``pod_pending`` across cross-pod epochs (or a
-    missed reset) breaks the exact equality."""
-    out = devices8("""
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.launch import mesh as mesh_lib
-from repro.core import policies, sync
-
-mesh = mesh_lib.make_mesh((2, 4), ("pod", "data"))
-R, HIER, T = 8, 3, 9
-pol = policies.bsp()                     # sync epoch every step
-x0 = {"w": jnp.zeros(4, jnp.float32)}
-
-def local(p, s, u):
-    sq = lambda t: jax.tree.map(lambda x: x[0], t)
-    ex = lambda t: jax.tree.map(lambda x: x[None], t)
-    p2, s2, _ = sync.apply_and_sync(sq(p), sq(s), sq(u), pol,
-                                    dp_axes=("pod", "data"),
-                                    hierarchy=HIER, pod_axis="pod")
-    return ex(p2), ex(s2)
-
-stack = lambda t: jax.tree.map(lambda x: jnp.stack([x] * R), t)
-spec = lambda t: jax.tree.map(
-    lambda x: P(("pod", "data"), *([None] * (x.ndim - 1))), t)
-params = stack(x0)
-state = stack(sync.init_sync_state(x0, hierarchy=HIER))
-fn = jax.jit(jax.shard_map(
-    local, mesh=mesh,
-    in_specs=(spec(params), spec(state), spec(params)),
-    out_specs=(spec(params), spec(state)), check_vma=False))
-
-S_pod = [1.0 + 2 + 3 + 4, 5.0 + 6 + 7 + 8]   # per-step update mass per pod
-for t in range(T):
-    u = {"w": jnp.stack([jnp.full(4, float(r + 1), jnp.float32)
-                         for r in range(R)])}
-    params, state = fn(params, state, u)
-    w = np.asarray(params["w"])              # (R, 4)
-    crossed = 3 * ((t + 1) // HIER)          # epochs whose pend crossed pods
-    for r in range(R):
-        pod = r // 4
-        want = S_pod[pod] * (t + 1) + S_pod[1 - pod] * crossed
-        assert np.all(w[r] == want), (t, r, w[r], want)
-    pend = np.asarray(state["pod_pending"]  # noqa: F821
-                      if isinstance(state, dict) else state.pod_pending["w"])
-    if (t + 1) % HIER == 0:
-        assert np.all(pend == 0.0), (t, pend)   # reset after crossing
-        assert np.all(w == w[0]), t             # pods fully agree
-print("HIER_OK")
-""")
-    assert "HIER_OK" in out
 
 
 @pytest.mark.slow
@@ -219,7 +162,7 @@ from jax.sharding import PartitionSpec as P
 from repro.launch import mesh as mesh_lib
 from repro.core import policies, sync
 
-mesh = mesh_lib.make_mesh((2, 4), ("pod", "data"))
+mesh = mesh_lib.make_mesh((8,), ("data",))
 R, T = 8, 40
 pol = policies.bsp()
 x0 = {"w": jnp.zeros(4, jnp.float32)}
@@ -228,13 +171,13 @@ def local(p, s, u):
     sq = lambda t: jax.tree.map(lambda x: x[0], t)
     ex = lambda t: jax.tree.map(lambda x: x[None], t)
     p2, s2, _ = sync.apply_and_sync(sq(p), sq(s), sq(u), pol,
-                                    dp_axes=("pod", "data"),
+                                    dp_axes=("data",),
                                     compress="bf16")
     return ex(p2), ex(s2)
 
 stack = lambda t: jax.tree.map(lambda x: jnp.stack([x] * R), t)
 spec = lambda t: jax.tree.map(
-    lambda x: P(("pod", "data"), *([None] * (x.ndim - 1))), t)
+    lambda x: P("data", *([None] * (x.ndim - 1))), t)
 params = stack(x0)
 state = stack(sync.init_sync_state(x0, compress="bf16"))
 fn = jax.jit(jax.shard_map(
